@@ -20,9 +20,12 @@ for _p in (_ROOT, os.path.join(_ROOT, "src")):
         sys.path.insert(0, _p)
 
 
-def main() -> None:
+def main() -> int:
     if "--fast" in sys.argv:
         os.environ.setdefault("BENCH_EVENTS", "200000")
+    from repro.core.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     print("name,us_per_call,derived")
     t0 = time.time()
     from benchmarks import (
@@ -41,6 +44,7 @@ def main() -> None:
         roofline_table,
     )
 
+    failed = []
     for mod, label in (
         (bench_dfg_example, "table1"),
         (bench_memory_scaling, "fig4"),
@@ -61,9 +65,13 @@ def main() -> None:
                 print(",".join(str(x) for x in row), flush=True)
         except Exception as e:  # noqa: BLE001
             print(f"{label}_ERROR,0,{e!r}", flush=True)
+            failed.append(label)
     print(f"total_wall,{(time.time() - t0) * 1e6:.0f},seconds="
           f"{time.time() - t0:.1f}")
+    if failed:
+        print(f"failed modules: {', '.join(failed)}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -6,15 +6,19 @@ the block-size parameters (``block_e``, ``block_a``, ...), so for any
 concrete assignment of those parameters the checker can
 
 * bound the **VMEM working set** per grid step — Σ over operand/output
-  blocks of ``prod(block_shape) × dtype_bytes``, plus each kernel's
-  declared in-kernel scratch term (the one-hot / DP-front tiles the body
-  materializes, the same formulas ``pick_blocks`` budgets against);
+  blocks of their bytes once padded to whole (sublane, lane) tiles (a
+  ``(1, BE)`` event row fills 8 sublanes), plus each kernel's declared
+  in-kernel scratch term (the one-hot / DP-front tiles the body
+  materializes, the same formulas ``pick_blocks`` budgets against).
+  Operands placed in SMEM (``memory_space=...SMEM``, the dicing window)
+  are counted apart and never tiled;
 * flag **tile misalignment** against the MXU/VPU tiling rules — lane
   (minor) dimension a multiple of 128, sublane a multiple of 8/16/32 for
   4/2/1-byte dtypes.  Whole-array broadcast operands (constant index maps,
-  like the ``(1, 2)`` window) are exempt; under-sized power-of-two tiles
-  (the align kernel's small variant blocks) are *warnings* — Mosaic pads
-  them — while oversized unaligned tiles are hard errors.
+  like the align kernel's ``(S, 1)`` cost columns) are exempt; under-sized
+  power-of-two tiles (``(1, BE)`` rows, small variant blocks) are
+  *warnings* — Mosaic pads them — while oversized unaligned tiles are hard
+  errors.
 
 :func:`validate_blocks` is the assertion layer ``pick_blocks`` calls: it
 raises :class:`KernelResourceError` when a block assignment breaks the
@@ -28,6 +32,7 @@ from __future__ import annotations
 import ast
 import dataclasses
 import functools
+import math
 from pathlib import Path
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -84,26 +89,26 @@ KERNEL_TABLE: Dict[str, KernelSpec] = {
     "dfg_count": KernelSpec(
         rel="kernels/dfg_count/kernel.py",
         calls=(
-            # two (BE, BA) f32 one-hot tiles feed the MXU contraction
-            CallSpec("plain", ("int32", "int32", "bool"),
+            # two (BA, BE) f32 one-hot tiles feed the MXU contraction
+            CallSpec("plain", ("int32", "int32"),
                      "2 * 4 * block_e * block_a"),
             CallSpec("diced",
-                     ("int32", "int32", "bool",
-                      "float32", "float32", "float32"),
+                     ("int32", "int32", "float32", "float32", "float32"),
                      "2 * 4 * block_e * block_a"),
         ),
     ),
     "segment_count": KernelSpec(
         rel="kernels/segment_count/kernel.py",
         calls=(
-            # one (BN, BS) f32 one-hot tile
-            CallSpec("main", ("int32", "bool"), "4 * block_n * block_s"),
+            # one (BS, BN) f32 one-hot tile
+            CallSpec("main", ("int32",), "4 * block_n * block_s"),
         ),
     ),
     "align_dp": KernelSpec(
         rel="kernels/align_dp/kernel.py",
         calls=(
-            # DP front + one-hot + gathered M column, each (BV, S) f32
+            # DP front (VMEM scratch) + one-hot + gathered M column, each
+            # (S, BV) f32
             CallSpec("main",
                      ("int32", "int32", "float32", "float32", "float32"),
                      "3 * 4 * block_v * s"),
@@ -125,6 +130,7 @@ def _pkg_root() -> Path:
 class SpecShape:
     dims: Tuple[str, ...]  # symbolic dim expressions (unparsed AST)
     const_index_map: bool  # whole-array broadcast operand
+    smem: bool = False  # scalar memory: no VMEM bytes, no tiling
 
 
 @dataclasses.dataclass(frozen=True)
@@ -156,7 +162,11 @@ def _spec_shape(call: ast.Call) -> SpecShape:
             const_map = all(isinstance(e, ast.Constant) for e in body.elts)
         else:
             const_map = isinstance(body, ast.Constant)
-    return SpecShape(dims=dims, const_index_map=const_map)
+    space = next(
+        (k.value for k in call.keywords if k.arg == "memory_space"), None
+    )
+    smem = isinstance(space, ast.Attribute) and space.attr == "SMEM"
+    return SpecShape(dims=dims, const_index_map=const_map, smem=smem)
 
 
 def _resolve_spec(node: ast.AST, symbols: Dict[str, ast.Call]) -> ast.Call:
@@ -300,6 +310,21 @@ def _check_tiling(
     return errors, warnings
 
 
+def _tile_bytes(dims: Sequence[int], dtype: str) -> int:
+    """Bytes of a VMEM block padded to whole (sublane, lane) tiles."""
+    itemsize = DTYPE_BYTES.get(dtype, 4)
+    dims = list(dims)
+    if dims:
+        dims[-1] = -(-dims[-1] // LANE) * LANE
+    if len(dims) >= 2:
+        sub = MIN_SUBLANE.get(itemsize, 8)
+        dims[-2] = -(-dims[-2] // sub) * sub
+    n = itemsize
+    for d in dims:
+        n *= d
+    return n
+
+
 def estimate_call(
     kernel_name: str,
     call_index: int,
@@ -328,11 +353,18 @@ def estimate_call(
     errors: List[str] = []
     warnings: List[str] = []
     total = 0
+    smem = 0
     for i, (s, dtype) in enumerate(zip(site.in_specs, call.in_dtypes)):
         dims = [_eval_expr(d, env) for d in s.dims]
-        nbytes = DTYPE_BYTES.get(dtype, 4)
-        for d in dims:
-            nbytes *= d
+        if s.smem:
+            nbytes = DTYPE_BYTES.get(dtype, 4) * math.prod(dims)
+            smem += nbytes
+            operands.append({
+                "operand": f"in[{i}]", "block": dims, "dtype": dtype,
+                "bytes": nbytes, "memory_space": "smem",
+            })
+            continue
+        nbytes = _tile_bytes(dims, dtype)
         total += nbytes
         operands.append({
             "operand": f"in[{i}]", "block": dims, "dtype": dtype,
@@ -344,9 +376,7 @@ def estimate_call(
             warnings += w
     for i, s in enumerate(site.out_specs):
         dims = [_eval_expr(d, env) for d in s.dims]
-        nbytes = DTYPE_BYTES.get(site.out_dtype, 4)
-        for d in dims:
-            nbytes *= d
+        nbytes = _tile_bytes(dims, site.out_dtype)
         total += nbytes
         operands.append({
             "operand": f"out[{i}]", "block": dims, "dtype": site.out_dtype,
@@ -363,6 +393,7 @@ def estimate_call(
         "env": dict(sorted(env.items())),
         "operands": operands,
         "scratch_bytes": scratch,
+        "smem_bytes": smem,
         "vmem_bytes": total,
         "errors": errors,
         "warnings": warnings,

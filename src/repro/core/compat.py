@@ -1,15 +1,8 @@
-"""JAX version-compat shims.
+"""Mesh and shard_map front-ends on the installed JAX (0.9).
 
-The repo targets the current ``jax.shard_map`` / ``jax.sharding.AxisType``
-API but must also run on older jaxlibs (0.4.x) where:
-
-* ``jax.sharding.AxisType`` does not exist and ``jax.make_mesh`` takes no
-  ``axis_types`` keyword;
-* ``shard_map`` lives in ``jax.experimental.shard_map`` with
-  ``check_rep=``/``auto=`` instead of ``check_vma=``/``axis_names=``.
-
-Everything mesh- or shard_map-shaped in the codebase goes through these two
-helpers so the drift is handled in exactly one place.
+Everything mesh- or shard_map-shaped in the codebase goes through these
+helpers, so every mesh is built with all axes Auto and every shard_map
+spells its manual axes the same way.
 """
 
 from __future__ import annotations
@@ -18,12 +11,8 @@ import math
 from typing import Optional, Sequence
 
 import jax
-import numpy as np
 
 __all__ = ["make_mesh", "shard_map", "cost_analysis"]
-
-_HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-_HAS_JAX_SHARD_MAP = hasattr(jax, "shard_map")
 
 
 def make_mesh(
@@ -32,57 +21,29 @@ def make_mesh(
     *,
     devices: Optional[Sequence] = None,
 ):
-    """``jax.make_mesh`` with all axes Auto, on any supported jax version."""
+    """``jax.make_mesh`` with all axes Auto."""
     if devices is None:
         devices = jax.devices()[: math.prod(axis_shapes)]
-    if _HAS_AXIS_TYPE:
-        return jax.make_mesh(
-            tuple(axis_shapes),
-            tuple(axis_names),
-            devices=devices,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
-        )
-    try:
-        return jax.make_mesh(
-            tuple(axis_shapes), tuple(axis_names), devices=devices
-        )
-    except (AttributeError, TypeError):
-        return jax.sharding.Mesh(
-            np.asarray(devices).reshape(tuple(axis_shapes)), tuple(axis_names)
-        )
+    return jax.make_mesh(
+        tuple(axis_shapes),
+        tuple(axis_names),
+        devices=devices,
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axis_names),
+    )
 
 
 def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a dict on every jax version (pre-0.5
-    returns a one-element list of per-device dicts)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """``compiled.cost_analysis()``, empty when the backend reports none."""
+    return compiled.cost_analysis() or {}
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None, check=False):
-    """Uniform shard_map front-end.
-
-    ``axis_names`` (new-API meaning): the mesh axes the body is *manual*
-    over; remaining axes stay auto (partial-auto shard_map).  ``None`` means
-    manual over every axis.  ``check`` maps to ``check_vma`` (new) /
-    ``check_rep`` (old).
-    """
-    if _HAS_JAX_SHARD_MAP:
-        kw = dict(
-            mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
-        )
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
+    """``jax.shard_map``: ``axis_names`` are the mesh axes the body is
+    manual over (``None``: all of them; the rest stay auto), ``check`` maps
+    to ``check_vma``."""
     kw = dict(
-        mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check
+        mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
     )
     if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            kw["auto"] = auto
-    return _shard_map(f, **kw)
+        kw["axis_names"] = set(axis_names)
+    return jax.shard_map(f, **kw)

@@ -30,6 +30,7 @@ from .compat import shard_map
 
 __all__ = [
     "distributed_dfg",
+    "place_sharded",
     "shard_pairs",
     "local_dfg_fn",
     "merge_shard_psis",
@@ -96,6 +97,13 @@ def shard_pairs(
     )
 
 
+def place_sharded(mesh: Mesh, *arrays):
+    """Lay each array's leading axis out over every device of ``mesh``
+    (all axes flattened) — the placement both mesh paths use."""
+    sharding = NamedSharding(mesh, P(tuple(mesh.axis_names)))
+    return [jax.device_put(x, sharding) for x in arrays]
+
+
 def distributed_dfg(
     mesh: Mesh,
     src: np.ndarray,
@@ -136,11 +144,7 @@ def distributed_dfg(
         in_specs=(all_axes_spec, all_axes_spec, all_axes_spec),
         out_specs=P(),  # fully replicated aggregate — the only thing leaving
     )
-    sharding = NamedSharding(mesh, all_axes_spec)
-    args = [
-        jax.device_put(x, sharding) for x in (src_s, dst_s, valid_s)
-    ]
-    psi = jax.jit(mapped)(*args)
+    psi = jax.jit(mapped)(*place_sharded(mesh, src_s, dst_s, valid_s))
     return np.asarray(psi, dtype=np.int64)
 
 
@@ -203,7 +207,7 @@ def merge_shard_psis(
     mapped = shard_map(
         shard_fn, mesh=mesh, in_specs=(P(axes),), out_specs=P(),
     )
-    arg = jax.device_put(stack, NamedSharding(mesh, P(axes)))
+    (arg,) = place_sharded(mesh, stack)
     return np.asarray(jax.jit(mapped)(arg), dtype=np.int64)
 
 

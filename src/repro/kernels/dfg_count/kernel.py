@@ -4,20 +4,28 @@ GPU/graph-DB intuition would scatter-add each directly-follows pair into
 ``Ψ[src, dst]`` — scatters serialize on TPU.  The TPU-native formulation
 builds one-hot tiles **in VMEM** from the integer id blocks and accumulates
 
-    Ψ[i·BA:(i+1)·BA, j·BA:(j+1)·BA] += OneHot_src(block)ᵀ · OneHot_dst(block)
+    Ψ[i·BA:(i+1)·BA, j·BA:(j+1)·BA] += OneHot_src(block) · OneHot_dst(block)ᵀ
 
 on the MXU.  Grid ``(A/BA_src, A/BA_dst, E/BE)`` with the event dimension
 innermost (fastest-varying) so each output tile stays resident while the
 event stream flows through; the tile is zeroed at the first event block
 (standard Pallas accumulation pattern).
 
-VMEM working set per step (BE=1024, BA=128, f32):
-  2 one-hots 1024×128×4 B = 1 MiB + out tile 64 KiB  « 16 MiB v5e VMEM.
-MXU alignment: BE multiple of 8 (sublane), BA multiple of 128 (lane).
+Layout: each event block arrives as one lane-major ``(1, BE)`` row, and the
+one-hots are built transposed, ``(BA, BE) = iota over sublanes == row``, so
+no vector ever changes shape in the kernel (Mosaic cannot relayout a 1-D
+block into a column).  Pairs that do not count carry the id ``-1``, which
+matches no row of the one-hot; the wrapper folds the valid mask into the
+ids, so the kernel reads int32 ids only.
+
+VMEM working set per step (BE=4096, BA=128, f32):
+  2 one-hots 128×4096×4 B = 4 MiB + out tile 64 KiB  « 16 MiB v5e VMEM.
+MXU alignment: BE and BA multiples of 128 (lane).
 
 The fused **dicing** variant additionally streams the pair timestamps and
 applies ``t0 ≤ t < t1`` in-register — the paper's WHERE clause at zero extra
-HBM traffic (no filtered copy is ever materialized).
+HBM traffic (no filtered copy is ever materialized).  The window sits in
+SMEM as two f32 scalars.
 """
 
 from __future__ import annotations
@@ -27,81 +35,59 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["dfg_kernel", "dfg_dice_kernel", "dfg_count_pallas"]
 
 
-def dfg_kernel(src_ref, dst_ref, valid_ref, out_ref, *, block_a: int):
-    """One grid step: accumulate a (BA, BA) tile over one event block."""
-    i = pl.program_id(0)  # src-activity tile
-    j = pl.program_id(1)  # dst-activity tile
-    e = pl.program_id(2)  # event block (innermost)
+def _accumulate(src, dst, out_ref, block_a: int):
+    """``out += OneHot(src) · OneHot(dst)ᵀ`` for one (1, BE) event row."""
+    a0 = pl.program_id(0) * block_a
+    b0 = pl.program_id(1) * block_a
+    rows = jax.lax.broadcasted_iota(jnp.int32, (block_a, src.shape[1]), 0)
+    oh_src = (rows + a0 == src).astype(jnp.float32)  # (BA, BE)
+    oh_dst = (rows + b0 == dst).astype(jnp.float32)
+    out_ref[...] += jax.lax.dot_general(
+        oh_src,
+        oh_dst,
+        dimension_numbers=(((1,), (1,)), ((), ())),  # contract over events
+        preferred_element_type=jnp.float32,
+    )
 
-    @pl.when(e == 0)
+
+def dfg_kernel(src_ref, dst_ref, out_ref, *, block_a: int):
+    """One grid step: accumulate a (BA, BA) tile over one event block."""
+
+    @pl.when(pl.program_id(2) == 0)  # event block (innermost)
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    src = src_ref[...]  # (BE,) int32
-    dst = dst_ref[...]
-    valid = valid_ref[...]
-
-    a0 = i * block_a
-    b0 = j * block_a
-    cols = jax.lax.broadcasted_iota(jnp.int32, (src.shape[0], block_a), 1)
-    oh_src = (src[:, None] == (a0 + cols)) & valid[:, None]
-    oh_dst = dst[:, None] == (b0 + cols)
-    out_ref[...] += jax.lax.dot_general(
-        oh_src.astype(jnp.float32),
-        oh_dst.astype(jnp.float32),
-        dimension_numbers=(((0,), (0,)), ((), ())),  # contract over events
-        preferred_element_type=jnp.float32,
-    )
+    _accumulate(src_ref[...], dst_ref[...], out_ref, block_a)
 
 
 def dfg_dice_kernel(
-    src_ref, dst_ref, valid_ref, ts_src_ref, ts_dst_ref, win_ref, out_ref,
-    *, block_a: int
+    src_ref, dst_ref, ts_src_ref, ts_dst_ref, win_ref, out_ref, *, block_a: int
 ):
-    """Fused dicing: valid &= (t0 <= t_src) & (t_src < t1) & same for dst.
+    """Fused dicing: a pair counts iff ``t0 <= t < t1`` for both endpoints.
 
     Paper semantics — both endpoints of the pair must be inside the window."""
-    i = pl.program_id(0)
-    j = pl.program_id(1)
-    e = pl.program_id(2)
 
-    @pl.when(e == 0)
+    @pl.when(pl.program_id(2) == 0)
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    src = src_ref[...]
-    dst = dst_ref[...]
-    t0 = win_ref[0, 0]
-    t1 = win_ref[0, 1]
+    t0 = win_ref[0]
+    t1 = win_ref[1]
     ts_s = ts_src_ref[...]
     ts_d = ts_dst_ref[...]
-    valid = (
-        valid_ref[...]
-        & (ts_s >= t0) & (ts_s < t1)
-        & (ts_d >= t0) & (ts_d < t1)
-    )
-
-    a0 = i * block_a
-    b0 = j * block_a
-    cols = jax.lax.broadcasted_iota(jnp.int32, (src.shape[0], block_a), 1)
-    oh_src = (src[:, None] == (a0 + cols)) & valid[:, None]
-    oh_dst = dst[:, None] == (b0 + cols)
-    out_ref[...] += jax.lax.dot_general(
-        oh_src.astype(jnp.float32),
-        oh_dst.astype(jnp.float32),
-        dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    inside = (ts_s >= t0) & (ts_s < t1) & (ts_d >= t0) & (ts_d < t1)
+    src = jnp.where(inside, src_ref[...], -1)
+    _accumulate(src, dst_ref[...], out_ref, block_a)
 
 
 def dfg_count_pallas(
     src: jax.Array,
     dst: jax.Array,
-    valid: jax.Array,
     *,
     num_activities_padded: int,
     block_e: int,
@@ -111,13 +97,13 @@ def dfg_count_pallas(
     ts_dst: jax.Array | None = None,
     window: jax.Array | None = None,
 ) -> jax.Array:
-    """Raw pallas_call wrapper.  All shapes must be pre-padded:
-    len(src) % block_e == 0, num_activities_padded % block_a == 0."""
-    e_total = src.shape[0]
+    """Raw pallas_call wrapper.  Event columns are ``(1, E)`` rows with
+    ``E % block_e == 0``; ``num_activities_padded % block_a == 0``."""
+    e_total = src.shape[1]
     a_pad = num_activities_padded
     grid = (a_pad // block_a, a_pad // block_a, e_total // block_e)
 
-    ev_spec = pl.BlockSpec((block_e,), lambda i, j, e: (e,))
+    ev_spec = pl.BlockSpec((1, block_e), lambda i, j, e: (0, e))
     out_spec = pl.BlockSpec((block_a, block_a), lambda i, j, e: (i, j))
     out_shape = jax.ShapeDtypeStruct((a_pad, a_pad), jnp.float32)
 
@@ -126,19 +112,21 @@ def dfg_count_pallas(
         return pl.pallas_call(
             kern,
             grid=grid,
-            in_specs=[ev_spec, ev_spec, ev_spec],
+            in_specs=[ev_spec, ev_spec],
             out_specs=out_spec,
             out_shape=out_shape,
             interpret=interpret,
-        )(src, dst, valid)
+        )(src, dst)
 
-    win_spec = pl.BlockSpec((1, 2), lambda i, j, e: (0, 0))
+    win_spec = pl.BlockSpec(
+        (2,), lambda i, j, e: (0,), memory_space=pltpu.SMEM
+    )
     kern = functools.partial(dfg_dice_kernel, block_a=block_a)
     return pl.pallas_call(
         kern,
         grid=grid,
-        in_specs=[ev_spec, ev_spec, ev_spec, ev_spec, ev_spec, win_spec],
+        in_specs=[ev_spec, ev_spec, ev_spec, ev_spec, win_spec],
         out_specs=out_spec,
         out_shape=out_shape,
         interpret=interpret,
-    )(src, dst, valid, ts_src, ts_dst, window)
+    )(src, dst, ts_src, ts_dst, window)

@@ -40,16 +40,16 @@ def pick_blocks(
     return block_e, block_a
 
 
-def _pad_inputs(src, dst, valid, block_e):
+def _event_rows(block_e, src, valid, *cols):
+    """Fold ``valid`` into ``src`` (a pair that does not count carries id
+    -1, which matches no one-hot row), pad every column to a multiple of
+    ``block_e`` and lay each out as one lane-major ``(1, E)`` row."""
+    src = jnp.where(valid.astype(jnp.bool_), src.astype(jnp.int32), -1)
     n = src.shape[0]
-    pad = (-n) % block_e
-    if n == 0:
-        pad = block_e
-    if pad:
-        src = jnp.pad(src, (0, pad))
-        dst = jnp.pad(dst, (0, pad))
-        valid = jnp.pad(valid, (0, pad))
-    return src, dst, valid
+    pad = (-n) % block_e or (block_e if n == 0 else 0)
+    out = [jnp.pad(src, (0, pad), constant_values=-1)]
+    out += [jnp.pad(c, (0, pad)) for c in cols]
+    return [c.reshape(1, -1) for c in out]
 
 
 @functools.partial(
@@ -74,15 +74,9 @@ def dfg_count(
     block_a = block_a or auto_a
     a_pad = max(block_a, -(-num_activities // block_a) * block_a)
 
-    src = src.astype(jnp.int32)
-    dst = dst.astype(jnp.int32)
-    valid = valid.astype(jnp.bool_)
-    # padded ids land outside [0, A): mark them invalid via the id compare
-    # (padded src/dst are 0 — rely on the valid mask added by padding=False)
-    src, dst, valid = _pad_inputs(src, dst, valid, block_e)
-
+    src, dst = _event_rows(block_e, src, valid, dst.astype(jnp.int32))
     out = dfg_count_pallas(
-        src, dst, valid,
+        src, dst,
         num_activities_padded=a_pad,
         block_e=block_e,
         block_a=block_a,
@@ -116,29 +110,19 @@ def dfg_count_diced(
     block_a = block_a or auto_a
     a_pad = max(block_a, -(-num_activities // block_a) * block_a)
 
-    src = src.astype(jnp.int32)
-    dst = dst.astype(jnp.int32)
-    valid = valid.astype(jnp.bool_)
-    ts_src = ts_src.astype(jnp.float32)
-    ts_dst = ts_dst.astype(jnp.float32)
-    n = src.shape[0]
-    pad = (-n) % block_e or (block_e if n == 0 else 0)
-    if pad:
-        src = jnp.pad(src, (0, pad))
-        dst = jnp.pad(dst, (0, pad))
-        valid = jnp.pad(valid, (0, pad))
-        ts_src = jnp.pad(ts_src, (0, pad))
-        ts_dst = jnp.pad(ts_dst, (0, pad))
-
+    src, dst, ts_src, ts_dst = _event_rows(
+        block_e, src, valid, dst.astype(jnp.int32),
+        ts_src.astype(jnp.float32), ts_dst.astype(jnp.float32),
+    )
     out = dfg_count_pallas(
-        src, dst, valid,
+        src, dst,
         num_activities_padded=a_pad,
         block_e=block_e,
         block_a=block_a,
         interpret=interpret,
         ts_src=ts_src,
         ts_dst=ts_dst,
-        window=window.astype(jnp.float32).reshape(1, 2),
+        window=window.astype(jnp.float32).reshape(2),
     )
     return out[:num_activities, :num_activities].astype(jnp.int32)
 

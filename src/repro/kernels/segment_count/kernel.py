@@ -7,14 +7,20 @@ sorted edge keys.  On CPU that is ``np.bincount``; on TPU a scatter-add
 serializes, so — exactly like :mod:`repro.kernels.dfg_count` — the kernel
 reformulates the histogram as a dense one-hot contraction on the MXU:
 
-    counts[j·BS:(j+1)·BS] += Σ_block OneHot(ids)ᵀ · 1
+    counts[j·BS:(j+1)·BS] += Σ_block OneHot(ids) · 1
 
 Grid ``(S/BS, N/BN)`` with the id-block dimension innermost so each count
 tile stays resident in VMEM while the id stream flows through; the tile is
 zeroed at the first block (standard Pallas accumulation pattern).
 
-VMEM working set per step (BN=2048, BS=512, f32):
-  one-hot 2048×512×4 B = 4 MiB + out tile 2 KiB  « 16 MiB v5e VMEM.
+Layout, as in :mod:`repro.kernels.dfg_count`: each id block is one
+lane-major ``(1, BN)`` row, the one-hot is built transposed as
+``(BS, BN) = iota over sublanes == row`` and reduced over lanes into a
+``(BS, 1)`` count column.  Ids that do not count carry ``-1`` (the wrapper
+folds the valid mask in), so the kernel reads int32 ids only.
+
+VMEM working set per step (BN=4096, BS=512, f32):
+  one-hot 512×4096×4 B = 8 MiB + out tile 2 KiB  < 16 MiB v5e VMEM.
 """
 
 from __future__ import annotations
@@ -28,8 +34,8 @@ from jax.experimental import pallas as pl
 __all__ = ["segment_count_kernel", "segment_count_pallas"]
 
 
-def segment_count_kernel(ids_ref, valid_ref, out_ref, *, block_s: int):
-    """One grid step: accumulate a (1, BS) count tile over one id block."""
+def segment_count_kernel(ids_ref, out_ref, *, block_s: int):
+    """One grid step: accumulate a (BS, 1) count tile over one id block."""
     j = pl.program_id(0)  # segment tile
     b = pl.program_id(1)  # id block (innermost)
 
@@ -37,43 +43,37 @@ def segment_count_kernel(ids_ref, valid_ref, out_ref, *, block_s: int):
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    ids = ids_ref[...]  # (BN,) int32
-    valid = valid_ref[...]
-
-    s0 = j * block_s
-    cols = jax.lax.broadcasted_iota(jnp.int32, (ids.shape[0], block_s), 1)
-    onehot = (ids[:, None] == (s0 + cols)) & valid[:, None]
-    out_ref[...] += jnp.sum(
-        onehot.astype(jnp.float32), axis=0, keepdims=True
-    )
+    ids = ids_ref[...]  # (1, BN) int32
+    rows = jax.lax.broadcasted_iota(jnp.int32, (block_s, ids.shape[1]), 0)
+    onehot = (rows + j * block_s == ids).astype(jnp.float32)  # (BS, BN)
+    out_ref[...] += jnp.sum(onehot, axis=1, keepdims=True)
 
 
 def segment_count_pallas(
     ids: jax.Array,
-    valid: jax.Array,
     *,
     num_segments_padded: int,
     block_n: int,
     block_s: int,
     interpret: bool,
 ) -> jax.Array:
-    """Raw pallas_call wrapper.  All shapes must be pre-padded:
-    len(ids) % block_n == 0, num_segments_padded % block_s == 0."""
-    n_total = ids.shape[0]
+    """Raw pallas_call wrapper.  ``ids`` is a ``(1, N)`` row with
+    ``N % block_n == 0``; ``num_segments_padded % block_s == 0``."""
+    n_total = ids.shape[1]
     grid = (num_segments_padded // block_s, n_total // block_n)
 
-    id_spec = pl.BlockSpec((block_n,), lambda j, b: (b,))
-    out_spec = pl.BlockSpec((1, block_s), lambda j, b: (0, j))
+    id_spec = pl.BlockSpec((1, block_n), lambda j, b: (0, b))
+    out_spec = pl.BlockSpec((block_s, 1), lambda j, b: (j, 0))
 
     kernel = functools.partial(segment_count_kernel, block_s=block_s)
     out = pl.pallas_call(
         kernel,
         grid=grid,
-        in_specs=[id_spec, id_spec],
+        in_specs=[id_spec],
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct(
-            (1, num_segments_padded), jnp.float32
+            (num_segments_padded, 1), jnp.float32
         ),
         interpret=interpret,
-    )(ids, valid)
-    return out[0]
+    )(ids)
+    return out[:, 0]

@@ -60,16 +60,13 @@ def segment_count(
     block_s = block_s or auto_s
     s_pad = max(block_s, -(-num_segments // block_s) * block_s)
 
-    ids = ids.astype(jnp.int32)
-    valid = valid.astype(jnp.bool_)
+    ids = jnp.where(valid.astype(jnp.bool_), ids.astype(jnp.int32), -1)
     n = ids.shape[0]
     pad = (-n) % block_n or (block_n if n == 0 else 0)
-    if pad:
-        ids = jnp.pad(ids, (0, pad))
-        valid = jnp.pad(valid, (0, pad))
+    ids = jnp.pad(ids, (0, pad), constant_values=-1).reshape(1, -1)
 
     out = segment_count_pallas(
-        ids, valid,
+        ids,
         num_segments_padded=s_pad,
         block_n=block_n,
         block_s=block_s,

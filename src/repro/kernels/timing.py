@@ -1,7 +1,8 @@
 """Wall-clock timing hook for the Pallas kernel entry points.
 
-Each public op (``dfg_count``, ``dfg_count_diced``, ``segment_count``,
-``align_dp``) is wrapped once at import time; every call lands in the
+Each kernel entry point (``dfg_count``, ``dfg_count_diced``,
+``segment_count``, and the ``align_dp`` kernel call behind its numpy/pallas
+dispatch) is wrapped once at import time; every call lands in the
 process-global :func:`repro.obs.kernel_registry` as a
 ``kernel_seconds{kernel=<name>}`` histogram.  Kernels are process-wide
 jitted callables shared by every engine, so their timings live in the

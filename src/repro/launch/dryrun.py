@@ -102,6 +102,9 @@ def main():
     ap.add_argument("--opts", default=None,
                     help="JSON dict of ModelConfig overrides (perf iteration)")
     args = ap.parse_args()
+    from repro.core.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     os.makedirs(args.out, exist_ok=True)
     opts = json.loads(args.opts) if args.opts else None

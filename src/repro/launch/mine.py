@@ -6,7 +6,7 @@ declarative query engine (``repro.query``): the CLI states *what* to mine
     # generate a synthetic BPI-like log and mine it
     PYTHONPATH=src python -m repro.launch.mine --events 500000 --dice-days 30
 
-    # distributed DFG on the production mesh (placeholder devices)
+    # distributed DFG over a mesh of every device present
     PYTHONPATH=src python -m repro.launch.mine --events 200000 --distributed
 """
 
@@ -28,18 +28,15 @@ def main() -> None:
                     choices=["auto", "scatter", "onehot", "pallas",
                              "streaming"])
     ap.add_argument("--distributed", action="store_true",
-                    help="shard_map DFG over the production mesh "
-                         "(512 placeholder host devices)")
+                    help="shard_map DFG over a 1-D mesh of every device "
+                         "present")
     ap.add_argument("--min-count", type=int, default=10)
     ap.add_argument("--dot-out", default=None)
     args = ap.parse_args()
 
-    if args.distributed:
-        os.environ["XLA_FLAGS"] = (
-            "--xla_force_host_platform_device_count=512 "
-            + os.environ.get("XLA_FLAGS", "")
-        )
+    from repro.core.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     from repro.core import discover_dependency_graph, to_dot
     from repro.data import ProcessSpec, generate_memmap_log
     from repro.query import Q, QueryEngine
@@ -57,9 +54,11 @@ def main() -> None:
 
     mesh = None
     if args.distributed:
-        from repro.launch.mesh import make_production_mesh
+        import jax
 
-        mesh = make_production_mesh(multi_pod=True)
+        from repro.launch.mesh import make_test_mesh
+
+        mesh = make_test_mesh((len(jax.devices()),), ("data",))
     engine = QueryEngine(
         mesh=mesh,
         # --distributed pins the device path: lift the out-of-core budget so

@@ -20,6 +20,9 @@ def main() -> None:
     ap.add_argument("--max-batch", type=int, default=4)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
+    from repro.core.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     import jax
     import numpy as np

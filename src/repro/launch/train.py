@@ -29,6 +29,9 @@ def main() -> None:
     ap.add_argument("--mine", action="store_true",
                     help="process-mine the run's telemetry at the end")
     args = ap.parse_args()
+    from repro.core.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from repro.configs import get_config
     from repro.configs.base import TrainHParams
