@@ -100,7 +100,7 @@ snap = default_engine().metrics_snapshot()
 lat = snap["query_latency_seconds{backend=cache,sink=dfg}"]
 print(f"\ncache-hit latency: p50={lat['p50'] * 1e6:.0f}us "
       f"p99={lat['p99'] * 1e6:.0f}us over {lat['count']} hits "
-      f"(hit ratio {snap['engine_cache_hit_ratio']:.2f})")
+      f"(hit ratio {snap['engine_cache_hits_total'] / snap['engine_queries_total']:.2f})")
 
 # self-mining: the engine's own spans are an event log — mine the miner
 own = default_engine().own_telemetry()

@@ -10,10 +10,14 @@ global registry rather than any per-engine one — the engine merges both
 in ``metrics_snapshot()``.
 
 The wrapper blocks on the device result (``block_until_ready``) so the
-histogram records true wall time, not async dispatch time; callers
+histogram records dispatch to ready, not async dispatch time; callers
 consume the result synchronously anyway, so nothing is serialized that
-was not already.  The first observation of a jitted kernel includes its
-compile time — that *is* the wall time the triggering query paid.
+was not already.  The query engine puts the columns on the device itself
+before the call (its ``scan.h2d`` span, ``engine_h2d_bytes_total``), so
+for the engine's calls the time excludes the host→device copy; a caller
+that passes numpy arrays still pays the copy inside the call.  The first
+observation of a jitted kernel includes its compile time — that *is* the
+wall time the triggering query paid.
 """
 
 from __future__ import annotations
@@ -27,7 +31,8 @@ __all__ = ["timed_kernel"]
 
 
 def timed_kernel(name: str, fn):
-    """Wrap a kernel entry point; records wall seconds per call."""
+    """Wrap a kernel entry point; records seconds per call, dispatch to
+    ready."""
     hist = kernel_registry().histogram("kernel_seconds", kernel=name)
 
     @functools.wraps(fn)

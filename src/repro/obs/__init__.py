@@ -1,13 +1,18 @@
 """``repro.obs`` — observability for the process-query engine.
 
-Three pieces, all dependency-free (stdlib + numpy) so every engine tier can
-import them without cycles:
+Its pieces import nothing of the engine (stdlib, numpy, and JAX's
+profiler for the bridge below), so every engine tier can import them
+without cycles:
 
 * :mod:`repro.obs.trace` — :class:`QueryTrace`, a per-query execution trace
   of timed spans (parse → cache-probe → plan → scan/resume → merge → sink)
   attached to every :class:`repro.query.QueryResult` as ``result.trace``.
   Always-on and near-zero overhead: preallocated span slabs, raw
-  ``perf_counter`` reads, no string formatting on the hot path.
+  ``perf_counter`` reads, no string formatting on the hot path.  While a
+  JAX profiler session is active each span is mirrored as a TraceMe named
+  ``repro.<span>`` on the profiler's clock (``profile_begin``).
+* :mod:`repro.obs.process` — one ``gc.callbacks`` hook per process:
+  ``process_gc_pause_seconds{generation}`` and ``repro.gc.gen<k>`` spans.
 * :mod:`repro.obs.metrics` — a lock-protected :class:`MetricsRegistry` of
   counters and streaming histograms (p50/p95/p99 from fixed log-scale
   buckets, no sample retention), exported as a dict, JSON lines, or

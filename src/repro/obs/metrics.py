@@ -17,7 +17,8 @@ Design constraints (mirrors the engine's always-on tracing budget):
 
 A module-global :func:`kernel_registry` is kept separate from per-engine
 registries: Pallas kernels are process-wide jitted callables, so their
-wall-times aggregate across every engine in the process.
+wall-times aggregate across every engine in the process; the garbage
+collector's pauses are the process's too.
 """
 
 from __future__ import annotations
@@ -205,10 +206,16 @@ class MetricsRegistry:
     All child metrics share the registry lock.  Gauges are callbacks
     evaluated at export time (e.g. telemetry ring-buffer drop counts),
     so they cost nothing between snapshots.
+
+    ``reentrant`` makes the lock an ``RLock``: a registry written from a
+    ``gc`` callback (which can fire on any allocation, also on a thread
+    inside this registry's own export) must let that thread take it again.
     """
 
-    def __init__(self):
-        self._lock = make_lock("MetricsRegistry")
+    def __init__(self, *, reentrant: bool = False):
+        self._lock = (
+            threading.RLock() if reentrant else make_lock("MetricsRegistry")
+        )
         self._counters: Dict[Tuple[str, LabelItems], Counter] = {}
         self._histograms: Dict[Tuple[str, LabelItems], Histogram] = {}
         self._gauges: Dict[Tuple[str, LabelItems], Callable[[], float]] = {}
@@ -417,10 +424,12 @@ def prometheus_text(*registries: MetricsRegistry) -> str:
     return "".join(r.to_prometheus() for r in registries)
 
 
-_KERNEL_REGISTRY = MetricsRegistry()
+_KERNEL_REGISTRY = MetricsRegistry(reentrant=True)
 
 
 def kernel_registry() -> MetricsRegistry:
-    """Process-global registry for Pallas kernel wall-times
-    (``kernel_seconds{kernel=...}`` histograms, one per entry point)."""
+    """Process-global registry: Pallas kernel wall-times
+    (``kernel_seconds{kernel=...}`` histograms, one per entry point) and
+    the collector's pauses (``process_gc_pause_seconds{generation=...}``,
+    see :mod:`repro.obs.process`)."""
     return _KERNEL_REGISTRY

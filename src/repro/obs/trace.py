@@ -13,6 +13,17 @@ engine's own process mines as a DFG — see ``QueryEngine.own_telemetry``):
 
 ``parse`` → ``cache_probe`` → [``delta``] → ``plan`` → ``scan`` |
 ``merge`` → ``sink``
+
+A device count nests ``scan.prepare`` → ``scan.h2d`` → ``scan.device``
+inside ``scan``.
+
+While a JAX profiler session is active, ``begin``/``end`` also open and
+close a TraceMe named ``repro.<span>``, timed by the profiler's own clock,
+so the spans land on the host plane of the ``.xplane.pb`` beside the
+device's operations.  :func:`profile_begin` / :func:`profile_end` are the
+one bridge; the serving tier's ``serve.payload`` and the process's
+``gc.gen<k>`` spans use them too.  Without a session a span costs one flag
+check more and constructs nothing.
 """
 
 from __future__ import annotations
@@ -20,11 +31,28 @@ from __future__ import annotations
 from time import perf_counter
 from typing import Dict, List, NamedTuple, Optional, Tuple
 
+from jax.profiler import TraceAnnotation as _TraceMe
+
 from .context import TraceContext, new_span_id
 
-__all__ = ["Span", "QueryTrace", "NullTrace"]
+__all__ = ["Span", "QueryTrace", "NullTrace", "profile_begin", "profile_end"]
 
 _SLAB = 8
+_profiling = _TraceMe.is_enabled
+
+
+def profile_begin(name: str):
+    """An open TraceMe named ``repro.<name>`` while a profiler session is
+    active, else None.  Close it on the same thread with
+    :func:`profile_end`."""
+    if _profiling():
+        return _TraceMe("repro." + name)
+    return None
+
+
+def profile_end(me) -> None:
+    if me is not None:
+        me.__exit__(None, None, None)
 
 
 class Span(NamedTuple):
@@ -46,7 +74,7 @@ class QueryTrace:
         "actual_cost_s", "rows_scanned", "delta_rows", "total_s",
         "branches", "drift", "notes",
         "trace_id", "span_id", "parent_span_id", "sampled", "links",
-        "_t_start", "_names", "_t0", "_dur", "_n",
+        "_t_start", "_names", "_t0", "_dur", "_n", "_prof",
     )
 
     def __init__(self, query_id: int, sink: str, source: str):
@@ -76,6 +104,7 @@ class QueryTrace:
         self._t0 = [0.0] * _SLAB
         self._dur = [0.0] * _SLAB
         self._n = 0
+        self._prof: Optional[Dict[int, object]] = None  # slot -> open TraceMe
         self._t_start = perf_counter()
 
     # -- distributed identity ---------------------------------------------
@@ -105,7 +134,7 @@ class QueryTrace:
 
     # -- hot path ---------------------------------------------------------
 
-    def begin(self, name: str) -> int:
+    def _slot(self, name: str) -> int:
         i = self._n
         if i == len(self._names):
             self._names.extend([None] * i)
@@ -114,19 +143,31 @@ class QueryTrace:
         self._names[i] = name
         self._dur[i] = -1.0
         self._n = i + 1
+        return i
+
+    def begin(self, name: str) -> int:
+        i = self._slot(name)
+        if _profiling():
+            if self._prof is None:
+                self._prof = {}
+            self._prof[i] = profile_begin(name)
         self._t0[i] = perf_counter()
         return i
 
     def end(self, idx: int) -> None:
         self._dur[idx] = perf_counter() - self._t0[idx]
+        if self._prof is not None:
+            profile_end(self._prof.pop(idx, None))
 
     def add_span(self, name: str, t0: float, duration_s: float) -> int:
         """Record an externally-timed span (absolute ``perf_counter``
         start).  Used for intervals measured outside the trace's own
         begin/end pairing — e.g. the scheduler's queue wait, whose start
         stamp is taken on the event loop and whose end is observed on the
-        worker thread that finally picks the request up."""
-        i = self.begin(name)
+        worker thread that finally picks the request up.  Stamp-only: no
+        thread works through such an interval, so it is not mirrored to
+        the profiler."""
+        i = self._slot(name)
         self._t0[i] = t0
         self._dur[i] = max(duration_s, 0.0)
         return i
@@ -137,6 +178,10 @@ class QueryTrace:
         for i in range(self._n):        # close spans orphaned by errors
             if self._dur[i] < 0.0:
                 self._dur[i] = t - self._t0[i]
+        if self._prof is not None:
+            for me in self._prof.values():
+                profile_end(me)
+            self._prof = None
         return self
 
     # -- read side --------------------------------------------------------
@@ -164,11 +209,19 @@ class QueryTrace:
         return total
 
     def coverage(self) -> float:
-        """Fraction of wall time covered by recorded spans (spans are
-        sequential and non-overlapping, so a plain sum is exact)."""
+        """Fraction of wall time under top-level spans.  A nested span
+        (``scan.prepare`` inside ``scan``) lies inside its parent, so the
+        union of the span intervals is the top-level spans' sum."""
         if self.total_s <= 0.0:
             return 1.0
-        covered = sum(max(self._dur[i], 0.0) for i in range(self._n))
+        covered, reach = 0.0, float("-inf")
+        for t0, t1 in sorted(
+            (self._t0[i], self._t0[i] + max(self._dur[i], 0.0))
+            for i in range(self._n)
+        ):
+            if t1 > reach:
+                covered += t1 - max(t0, reach)
+                reach = t1
         return min(covered / self.total_s, 1.0)
 
     def add_branch(self, name: str, trace: "QueryTrace") -> None:

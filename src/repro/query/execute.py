@@ -34,6 +34,7 @@ import time
 from collections import OrderedDict
 from typing import Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.conformance import (
@@ -68,6 +69,7 @@ from repro.graph.shard import ShardedLog, sharded_log_name
 from repro.analysis.lockdep import make_lock
 from repro.obs import MetricsRegistry, QueryTrace, kernel_registry
 from repro.obs.context import TraceContext, mint_context
+from repro.obs.process import install_gc_hook
 from repro.obs.trace import NullTrace
 
 from .ast import (
@@ -388,6 +390,36 @@ def _zero_outside(psi: np.ndarray, keep_ids: np.ndarray) -> np.ndarray:
 # ---------------------------------------------------------------------------
 
 
+#: count backends that are not one jitted call on the default device, so
+#: their scans are not split into phases
+_HOST_COUNTS = ("numpy", "distributed")
+
+
+class _ScanPhases:
+    """The nested ``scan.prepare`` → ``scan.h2d`` → ``scan.device`` spans of
+    one device count.  Each phase is a span of the query's trace and one
+    observation of ``engine_scan_phase_seconds{phase}``."""
+
+    __slots__ = ("_tr", "_hists", "_span", "_slot", "_t0")
+
+    def __init__(self, tr: QueryTrace, hists: Dict[str, "Histogram"]):
+        self._tr = tr
+        self._hists = hists  # by span name
+        self._span: Optional[str] = None
+        self.next("scan.prepare")
+
+    def next(self, span: Optional[str]) -> None:
+        """Close the open phase and open ``span`` (None: close only)."""
+        t = time.perf_counter()
+        if self._span is not None:
+            self._tr.end(self._slot)
+            self._hists[self._span].observe(t - self._t0)
+        self._span = span
+        if span is not None:
+            self._slot = self._tr.begin(span)
+            self._t0 = time.perf_counter()
+
+
 class _TraceScope:
     """Thread-local ambient trace parent (``QueryEngine.trace_scope``):
     while entered, root queries on this thread bind as children of the
@@ -524,10 +556,21 @@ class QueryEngine:
             "delta_suffix_fraction",
             "Fraction of the log rescanned by a delta resume",
         )
-        m.gauge(
-            "engine_cache_hit_ratio", self._cache_hit_ratio,
-            "Result-cache hits over total queries",
+        self._h_scan_phase = {
+            "scan." + p: m.histogram(
+                "engine_scan_phase_seconds",
+                "Phases of a device count: prepare (pair columns, masks, "
+                "casts), h2d (copy to the device), device (dispatch until "
+                "the counts are back on the host)",
+                phase=p,
+            )
+            for p in ("prepare", "h2d", "device")
+        }
+        self._c_h2d_bytes = m.counter(
+            "engine_h2d_bytes_total",
+            "Bytes of columns put on the device by device counts",
         )
+        install_gc_hook()
         # always-on per-query tracing + self-mining forensics: every
         # finished trace batches its spans into a bounded collector, so
         # ``Q.log(engine.own_telemetry())`` mines the engine's own process
@@ -605,10 +648,6 @@ class QueryEngine:
             conformance_queries=self._c_conformance.value,
             shard_queries=self._c_shard.value,
         )
-
-    def _cache_hit_ratio(self) -> float:
-        q = self._c_queries.value
-        return self._c_cache_hits.value / q if q else 0.0
 
     def metrics_snapshot(self, floor: int = 0) -> Dict[str, object]:
         """Engine registry + process-wide Pallas kernel timings, one flat
@@ -1815,11 +1854,19 @@ class QueryEngine:
                     self._repo_memo.popitem(last=False)
         return repo
 
+    def _scan_phases(self, physical: PhysicalPlan) -> Optional[_ScanPhases]:
+        """Open ``scan.prepare`` in the running query's trace where the
+        planned count runs on the device; None where it does not."""
+        if physical.backend in _HOST_COUNTS:
+            return None
+        return _ScanPhases(self._current_trace(), self._h_scan_phase)
+
     def _dfg_on_repo(
         self, st: _Collected, logical: LogicalPlan, physical: PhysicalPlan
     ):
         repo = st.repo
         names = list(repo.activity_names)
+        phases = self._scan_phases(physical)
         src, dst, valid = repo.df_pairs()
         window_fused = physical.fused_dicing and st.window is not None
 
@@ -1843,7 +1890,7 @@ class QueryEngine:
         else:
             a_count = repo.num_activities
 
-        psi = self._count(src, dst, valid, a_count, st, physical, repo)
+        psi = self._count(src, dst, valid, a_count, st, physical, repo, phases)
 
         if physical.view_pushdown:
             vis = [i for i, l in enumerate(labels) if l != HIDDEN]
@@ -1858,7 +1905,13 @@ class QueryEngine:
     def _count(
         self, src, dst, valid, a_count, st: _Collected,
         physical: PhysicalPlan, repo: EventRepository,
+        phases: Optional[_ScanPhases],
     ) -> np.ndarray:
+        """Ψ from pair columns.  On the device backends ``phases`` (opened
+        by the caller before it built the columns) closes ``scan.prepare``
+        once the columns have the kernel's dtypes, times their explicit
+        copy to the device as ``scan.h2d``, and the call until the counts
+        are back on the host as ``scan.device``."""
         backend = physical.backend
         if backend == "numpy":
             return dfg_numpy(
@@ -1869,19 +1922,34 @@ class QueryEngine:
                 self.mesh, np.asarray(src, np.int32), np.asarray(dst, np.int32),
                 np.asarray(valid, bool), a_count,
             )
-        if backend == "pallas" and physical.fused_dicing and st.window is not None:
+        fused = (
+            backend == "pallas" and physical.fused_dicing
+            and st.window is not None
+        )
+        cols = [
+            np.asarray(src, np.int32), np.asarray(dst, np.int32),
+            np.asarray(valid, bool),
+        ]
+        if fused:
+            # one cast of the times; the pair columns are views of it
+            ts = np.asarray(repo.event_time, np.float32)
+            cols += [
+                ts[:-1], ts[1:],
+                np.asarray([st.window.t0, st.window.t1], np.float32),
+            ]
+        phases.next("scan.h2d")
+        on_device = jax.block_until_ready(jax.device_put(cols))
+        self._c_h2d_bytes.inc(sum(c.nbytes for c in cols))
+        phases.next("scan.device")
+        if fused:
             from repro.kernels.dfg_count import ops as _ops
 
-            ts = repo.event_time
-            out = _ops.dfg_count_diced(
-                np.asarray(src, np.int32), np.asarray(dst, np.int32),
-                np.asarray(valid, bool),
-                ts[:-1], ts[1:],
-                np.asarray([st.window.t0, st.window.t1]),
-                num_activities=a_count,
-            )
-            return np.asarray(out, dtype=np.int64)
-        return dfg(src, dst, valid, a_count, backend=backend)
+            out = _ops.dfg_count_diced(*on_device, num_activities=a_count)
+            psi = np.asarray(out, dtype=np.int64)
+        else:
+            psi = dfg(*on_device, a_count, backend=backend)
+        phases.next(None)
+        return psi
 
     def _histogram_on_repo(self, st: _Collected):
         repo = st.repo
@@ -2369,6 +2437,7 @@ class QueryEngine:
         planned backend (window as pair predicate or fused into the
         kernel), raw node counts alongside, then the shared derivation."""
         repo = st.repo
+        phases = self._scan_phases(physical)
         src, dst, valid = repo.df_pairs()
         window_fused = physical.fused_dicing and st.window is not None
         ev_mask = np.ones(repo.num_events, dtype=bool)
@@ -2380,7 +2449,7 @@ class QueryEngine:
                     repo, (st.window.t0, st.window.t1)
                 )
         psi = self._count(
-            src, dst, valid, repo.num_activities, st, physical, repo
+            src, dst, valid, repo.num_activities, st, physical, repo, phases
         )
         counts = np.bincount(
             repo.event_activity[ev_mask], minlength=repo.num_activities

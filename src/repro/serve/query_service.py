@@ -56,6 +56,7 @@ from __future__ import annotations
 
 import dataclasses
 import threading
+import time
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -64,6 +65,7 @@ from repro.analysis.lockdep import make_lock
 from repro.core.streaming import MemmapLog, MemmapLogWriter
 from repro.core.views import AccessDenied, AccessPolicy, ActivityView
 from repro.graph.shard import ShardedLog
+from repro.obs.trace import profile_begin, profile_end
 from repro.query import (
     AlignmentsSink,
     ApplyView,
@@ -207,6 +209,9 @@ class QueryService:
         # one lock per registered name: appends write three column files +
         # meta.json and must never interleave on the same log
         self._append_locks: Dict[str, threading.Lock] = {}
+        # serve_payload_seconds{sink} by sink; a racing insert stores the
+        # registry's one series for the sink either way
+        self._payload_hists: Dict[str, object] = {}
 
     # -- registry ------------------------------------------------------------
     def register(
@@ -624,20 +629,49 @@ class QueryService:
         else:
             sources, grant = self._resolve(names)
         q = self._build_query(request, sources, names, grant)
-        floor = grant.floor
+        res = self._run(q, sink, request, grant, model_src)
+        # serve.payload: the answer as JSON-shaped lists and dicts
+        t0 = time.perf_counter()
+        me = profile_begin("serve.payload")
+        try:
+            payload = self._payload(sink, res, grant.floor)
+            payload.update({
+                "log": names[0] if multi is None else None,
+                "logs": names if multi is not None else None,
+                "sink": sink,
+                "from_cache": res.from_cache,
+                "backend": res.physical.backend,
+                "wall_s": res.wall_s,
+                # the execution's distributed-trace id (a cache hit reports
+                # the hit's own trace; its links name the populating run)
+                "trace_id": (
+                    res.trace.trace_id if res.trace is not None else None
+                ),
+            })
+            if request.get("trace"):
+                payload["trace"] = (
+                    res.trace.to_dict() if res.trace is not None else None
+                )
+        finally:
+            profile_end(me)
+        hist = self._payload_hists.get(sink)
+        if hist is None:
+            hist = self._payload_hists[sink] = self.engine.metrics.histogram(
+                "serve_payload_seconds",
+                "Building a query's answer: lists, floors, the payload dict",
+                sink=sink,
+            )
+        hist.observe(time.perf_counter() - t0)
+        return payload
+
+    def _run(self, q, sink: str, request: Dict, grant: _Grant, model_src):
+        """Execute the request's query for ``sink``."""
+        backend = request.get("backend", "auto")
         if sink == "dfg":
-            res = q.dfg(backend=request.get("backend", "auto"))
-            psi = res.value
-            if floor:
-                psi = np.where(psi >= floor, psi, 0)
-            payload = {"psi": psi.tolist(), "names": res.names}
-        elif sink == "histogram":
-            res = q.histogram()
-            counts = res.value
-            if floor:
-                counts = np.where(counts >= floor, counts, 0)
-            payload = {"counts": counts.tolist(), "names": res.names}
-        elif sink == "variants":
+            return q.dfg(backend=backend)
+        if sink == "histogram":
+            return q.histogram()
+        if sink == "variants":
             if grant.has_view:
                 # variant sequences spell out raw activity names
                 raise AccessDenied(
@@ -645,38 +679,27 @@ class QueryService:
                     "under a view policy"
                 )
             k = request.get("k")
-            res = q.variants(int(k) if k is not None else None)
-            tv = res.value
-            keep = (
-                tv.counts >= floor if floor
-                else np.ones(len(tv.counts), dtype=bool)
-            )
-            payload = {
-                "counts": tv.counts[keep].tolist(),
-                "sequences": [s for s, ok in zip(tv.sequences, keep) if ok],
-            }
-        elif sink == "process_map":
-            res = q.process_map(
+            return q.variants(int(k) if k is not None else None)
+        if sink == "process_map":
+            return q.process_map(
                 top=float(request.get("top", 0.2)),
                 edge_top=(
                     float(request["edge_top"])
                     if request.get("edge_top") is not None
                     else None
                 ),
-                backend=request.get("backend", "auto"),
+                backend=backend,
             )
-            payload = self._floor_process_map(res.value, floor)
-        elif sink == "neighborhood":
+        if sink == "neighborhood":
             if request.get("activity") is None:
                 raise KeyError('"neighborhood" requests need an "activity"')
-            res = q.neighborhood(
+            return q.neighborhood(
                 str(request["activity"]),
                 k=int(request.get("k", 1)),
                 direction=str(request.get("direction", "out")),
-                backend=request.get("backend", "auto"),
+                backend=backend,
             )
-            payload = self._floor_neighborhood(res.value, floor)
-        elif sink in ("fitness", "alignments"):
+        if sink in ("fitness", "alignments"):
             model = None
             if model_src is not None:
                 from repro.query.ast import FitnessSink
@@ -688,68 +711,71 @@ class QueryService:
                 model = self.engine._model_for_source(
                     FitnessSink(), (), model_src, st
                 )
-            backend = request.get("backend", "auto")
             if sink == "fitness":
-                res = q.fitness(model, backend=backend)
-                rr = res.value
-                payload = {
-                    "fitness": rr.fitness,
-                    "perfect_traces": rr.perfectly_fitting,
-                    "total_traces": int(rr.trace_fitness.shape[0]),
-                    "deviations": self._floor_census(rr, floor),
-                }
-            else:
-                res = q.alignments(model, backend=backend)
-                ar = res.value
-                payload = {
-                    "fitness": ar.fitness,
-                    "perfect_traces": ar.perfectly_fitting,
-                    "total_traces": int(ar.trace_cost.shape[0]),
-                    "mean_cost": (
-                        float(ar.trace_cost.mean())
-                        if ar.trace_cost.shape[0] else 0.0
-                    ),
-                    "empty_cost": ar.empty_cost,
-                    "deviations": self._floor_census(ar, floor),
-                }
-        elif sink == "compare":
-            res = q.compare(backend=request.get("backend", "auto"))
-            cr = res.value
-            # the k-anonymity floor applies to every exposed matrix; drift
-            # is recomputed from the floored Ψs so sub-floor counts cannot
-            # be reconstructed from a (raw) difference
-            psis = [
-                np.where(p >= floor, p, 0) if floor else p for p in cr.psis
-            ]
-            payload = {
-                "names": cr.names,
-                "psi": {n: p.tolist() for n, p in zip(cr.log_names, psis)},
-                "diff": {
-                    n: (p - psis[0]).tolist()
-                    for n, p in zip(cr.log_names, psis)
-                },
-                "fitness": {
-                    n: f for n, f in zip(cr.log_names, cr.fitness)
-                },
-            }
-        else:
-            raise QueryPlanError(f"unknown sink {sink!r}")
+                return q.fitness(model, backend=backend)
+            return q.alignments(model, backend=backend)
+        if sink == "compare":
+            return q.compare(backend=backend)
+        raise QueryPlanError(f"unknown sink {sink!r}")
 
-        payload.update({
-            "log": names[0] if multi is None else None,
-            "logs": names if multi is not None else None,
-            "sink": sink,
-            "from_cache": res.from_cache,
-            "backend": res.physical.backend,
-            "wall_s": res.wall_s,
-            # the execution's distributed-trace id (a cache hit reports the
-            # hit's own trace; its links name the populating run)
-            "trace_id": (
-                res.trace.trace_id if res.trace is not None else None
-            ),
-        })
-        if request.get("trace"):
-            payload["trace"] = (
-                res.trace.to_dict() if res.trace is not None else None
+    def _payload(self, sink: str, res, floor: int) -> Dict:
+        """The sink's answer from ``res``, k-anonymity ``floor`` applied."""
+        if sink == "dfg":
+            psi = res.value
+            if floor:
+                psi = np.where(psi >= floor, psi, 0)
+            return {"psi": psi.tolist(), "names": res.names}
+        if sink == "histogram":
+            counts = res.value
+            if floor:
+                counts = np.where(counts >= floor, counts, 0)
+            return {"counts": counts.tolist(), "names": res.names}
+        if sink == "variants":
+            tv = res.value
+            keep = (
+                tv.counts >= floor if floor
+                else np.ones(len(tv.counts), dtype=bool)
             )
-        return payload
+            return {
+                "counts": tv.counts[keep].tolist(),
+                "sequences": [s for s, ok in zip(tv.sequences, keep) if ok],
+            }
+        if sink == "process_map":
+            return self._floor_process_map(res.value, floor)
+        if sink == "neighborhood":
+            return self._floor_neighborhood(res.value, floor)
+        if sink == "fitness":
+            rr = res.value
+            return {
+                "fitness": rr.fitness,
+                "perfect_traces": rr.perfectly_fitting,
+                "total_traces": int(rr.trace_fitness.shape[0]),
+                "deviations": self._floor_census(rr, floor),
+            }
+        if sink == "alignments":
+            ar = res.value
+            return {
+                "fitness": ar.fitness,
+                "perfect_traces": ar.perfectly_fitting,
+                "total_traces": int(ar.trace_cost.shape[0]),
+                "mean_cost": (
+                    float(ar.trace_cost.mean())
+                    if ar.trace_cost.shape[0] else 0.0
+                ),
+                "empty_cost": ar.empty_cost,
+                "deviations": self._floor_census(ar, floor),
+            }
+        # compare
+        cr = res.value
+        # the k-anonymity floor applies to every exposed matrix; drift is
+        # recomputed from the floored Ψs so sub-floor counts cannot be
+        # reconstructed from a (raw) difference
+        psis = [np.where(p >= floor, p, 0) if floor else p for p in cr.psis]
+        return {
+            "names": cr.names,
+            "psi": {n: p.tolist() for n, p in zip(cr.log_names, psis)},
+            "diff": {
+                n: (p - psis[0]).tolist() for n, p in zip(cr.log_names, psis)
+            },
+            "fitness": {n: f for n, f in zip(cr.log_names, cr.fitness)},
+        }

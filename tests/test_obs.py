@@ -223,7 +223,12 @@ def test_every_result_carries_a_trace(repo, engine):
     tr = res.trace
     assert tr is not None and tr.enabled
     names = [s.name for s in tr.spans]
-    assert names == ["parse", "cache_probe", "plan", "scan", "sink"]
+    # a count on the device nests its phases inside ``scan``
+    nested = (
+        [] if res.physical.backend == "numpy"
+        else ["scan.prepare", "scan.h2d", "scan.device"]
+    )
+    assert names == ["parse", "cache_probe", "plan", "scan", *nested, "sink"]
     assert tr.executed_backend == tr.planned_backend
     assert tr.predicted_cost_s is not None and tr.actual_cost_s is not None
     assert tr.rows_scanned == repo.num_events
@@ -452,3 +457,168 @@ def test_service_metrics_sink(repo):
     prom = svc.query({"sink": "metrics", "format": "prometheus"})
     assert "engine_queries_total" in prom["prometheus"]
     assert "kernel_seconds" in prom["prometheus"]
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock, scan phases, payload and collector pauses
+# ---------------------------------------------------------------------------
+
+
+def _host_event_names(log_dir) -> set:
+    import glob
+
+    import jax
+
+    (path,) = glob.glob(str(log_dir / "**" / "*.xplane.pb"), recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    return {
+        ev.name
+        for plane in data.planes if plane.name.startswith("/host")
+        for line in plane.lines for ev in line.events
+    }
+
+
+def test_spans_reach_the_profiler_host_plane(repo, tmp_path):
+    import jax
+
+    svc = QueryService(QueryEngine(tiny_pairs=0))  # a device count
+    svc.register("main", repo)
+    with jax.profiler.trace(str(tmp_path)):
+        out = svc.query({"log": "main", "sink": "dfg"})
+    assert out["backend"] not in ("numpy", "distributed")
+    names = _host_event_names(tmp_path)
+    assert {
+        "repro.parse", "repro.scan", "repro.scan.prepare", "repro.scan.h2d",
+        "repro.scan.device", "repro.serve.payload",
+    } <= names
+    # externally timed intervals stay stamp-only
+    assert not any(n.startswith("repro.queue_wait") for n in names)
+
+
+def test_no_trace_me_is_built_without_a_profiler_session(repo, monkeypatch,
+                                                         tmp_path):
+    import gc
+
+    import jax
+
+    import repro.obs.trace as obs_trace
+
+    built = []
+
+    class Counting(obs_trace._TraceMe):
+        def __init__(self, name, **kw):
+            built.append(name)
+            super().__init__(name, **kw)
+
+    monkeypatch.setattr(obs_trace, "_TraceMe", Counting)
+    svc = QueryService(QueryEngine(tiny_pairs=0))
+    svc.register("main", repo)
+    svc.query({"log": "main", "sink": "dfg"})
+    svc.query({"log": "main", "sink": "histogram"})
+    gc.collect()
+    assert built == []
+    # the same calls under a session do build them
+    with jax.profiler.trace(str(tmp_path)):
+        svc.query({"log": "main", "sink": "histogram"})
+    assert "repro.serve.payload" in built
+
+
+def test_scan_phases_and_h2d_bytes_of_one_query(repo, monkeypatch):
+    import jax
+
+    moved = []
+    put = jax.device_put
+
+    def spy(x, *a, **kw):
+        moved.append(sum(np.asarray(c).nbytes for c in jax.tree.leaves(x)))
+        return put(x, *a, **kw)
+
+    monkeypatch.setattr(jax, "device_put", spy)
+    engine = QueryEngine(tiny_pairs=0)
+    ts = repo.event_time
+    t0, t1 = float(np.quantile(ts, 0.2)), float(np.quantile(ts, 0.7))
+    res = Q.log(repo).using(engine).window(t0, t1).dfg(backend="pallas")
+    assert res.physical.fused_dicing
+    snap = engine.metrics_snapshot()
+    assert len(moved) == 1
+    # two int32 ids, one bool and two float32 times per pair, the window
+    assert snap["engine_h2d_bytes_total"] == moved[0]
+    assert moved[0] == 17 * (repo.num_events - 1) + 8
+    for phase in ("prepare", "h2d", "device"):
+        h = snap[f"engine_scan_phase_seconds{{phase={phase}}}"]
+        assert h["count"] == 1 and h["sum"] > 0.0
+    spans = {s.name: s for s in res.trace.spans}
+    scan = spans["scan"]
+    for name in ("scan.prepare", "scan.h2d", "scan.device"):
+        s = spans[name]
+        assert scan.start_s <= s.start_s
+        assert s.start_s + s.duration_s <= scan.start_s + scan.duration_s
+    # the same bits as the numpy count
+    ref = Q.log(repo).using(QueryEngine()).window(t0, t1).dfg(backend="numpy")
+    np.testing.assert_array_equal(res.value, ref.value)
+    # a count on the host opens no phase and moves nothing
+    Q.log(repo).using(engine).dfg(backend="numpy")
+    snap = engine.metrics_snapshot()
+    assert snap["engine_scan_phase_seconds{phase=h2d}"]["count"] == 1
+
+
+def test_coverage_counts_nested_spans_once():
+    tr = QueryTrace(1, "dfg", "repository")
+    t = 100.0
+    tr.add_span("parse", t, 0.1)
+    tr.add_span("scan", t + 0.1, 0.6)
+    tr.add_span("scan.prepare", t + 0.1, 0.2)
+    tr.add_span("scan.device", t + 0.3, 0.4)
+    tr.add_span("sink", t + 0.8, 0.1)
+    tr.total_s = 1.0
+    # top-level spans: parse 0.1 + scan 0.6 + sink 0.1
+    assert tr.coverage() == pytest.approx(0.8)
+
+
+def test_forensics_with_nested_scan_spans_matches_oracle(repo):
+    engine = QueryEngine(tiny_pairs=0)
+    Q.log(repo).using(engine).dfg()
+    Q.log(repo).using(engine).dfg()          # cache hit
+    Q.log(repo).using(engine).histogram()
+    own = engine.own_telemetry()
+    res = Q.log(own).using(QueryEngine()).dfg()
+    src, dst, valid = own.df_pairs()
+    expect = dfg_numpy(src, dst, valid, own.num_activities)
+    psi = np.asarray(res.value)
+    np.testing.assert_array_equal(psi, expect)
+    chain = ["scan", "scan.prepare", "scan.h2d", "scan.device", "sink"]
+    for a, b in zip(chain, chain[1:]):
+        assert psi[res.names.index(a), res.names.index(b)] >= 1
+
+
+def test_payload_seconds_by_sink_and_exported(repo):
+    svc = QueryService()
+    svc.register("main", repo)
+    svc.query({"log": "main", "sink": "dfg"})
+    svc.query({"log": "main", "sink": "dfg"})    # a cache hit builds one too
+    svc.query({"log": "main", "sink": "histogram"})
+    snap = svc.engine.metrics_snapshot()
+    assert snap["serve_payload_seconds{sink=dfg}"]["count"] == 2
+    assert snap["serve_payload_seconds{sink=histogram}"]["count"] == 1
+    assert "engine_cache_hit_ratio" not in snap
+    prom = svc.query({"sink": "metrics", "format": "prometheus"})["prometheus"]
+    assert 'serve_payload_seconds_count{sink="dfg"} 2' in prom
+    assert "engine_scan_phase_seconds" in prom
+    assert "engine_h2d_bytes_total" in prom
+    assert 'process_gc_pause_seconds_count{generation="2"}' in prom
+
+
+def test_gc_pauses_are_counted_once_per_process():
+    import gc
+
+    from repro.obs.process import _on_gc, install_gc_hook
+
+    install_gc_hook()
+    install_gc_hook()
+
+    assert gc.callbacks.count(_on_gc) == 1
+    h = kernel_registry().histogram("process_gc_pause_seconds", generation="2")
+    n, total = h.count, h.sum
+    gc.collect()
+    assert h.count == n + 1
+    assert h.sum > total
