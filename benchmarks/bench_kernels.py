@@ -24,17 +24,17 @@ def _time(fn, reps=3):
 
 def kernel_analytic_v5e(n_pairs: int, num_acts: int) -> dict:
     """Roofline terms of the kernel's block schedule on one v5e core."""
-    be, ba = pick_blocks(num_acts)
-    a_pad = max(ba, -(-num_acts // ba) * ba)
+    be, bs, bd = pick_blocks(num_acts)
+    tiles = -(-num_acts // bs) * -(-num_acts // bd)
     e_pad = max(be, -(-n_pairs // be) * be)
-    grid = (a_pad // ba) * (a_pad // ba) * (e_pad // be)
-    # per grid step: build 2 one-hots (BE·BA cmp) + matmul 2·BE·BA·BA flops
-    flops = grid * 2 * be * ba * ba
-    # HBM traffic: ids re-read per (i,j) tile + output written once
-    bytes_hbm = (a_pad // ba) ** 2 * e_pad * (4 + 4 + 1) + a_pad * a_pad * 4
+    # per grid step: build 2 int8 one-hots + matmul 2·BE·BS·BD int8 ops
+    flops = tiles * (e_pad // be) * 2 * be * bs * bd
+    # HBM traffic: the int32 id rows re-read per output tile + output once
+    bytes_hbm = tiles * e_pad * (4 + 4) + tiles * bs * bd * 4
     return {
-        "block_e": be, "block_a": ba, "grid": grid,
-        "compute_s": flops / hw.PEAK_FLOPS_BF16,
+        "block_e": be, "block_s": bs, "block_d": bd,
+        "grid": tiles * (e_pad // be),
+        "compute_s": flops / hw.PEAK_OPS_INT8,
         "memory_s": bytes_hbm / hw.HBM_BW,
         "flops": flops,
     }
@@ -70,7 +70,7 @@ def run() -> list:
         rows.append((
             f"dfg_pallas_v5e_{n_pairs}x{acts}",
             max(a["compute_s"], a["memory_s"]) * 1e6,
-            f"analytic;blocks=({a['block_e']},{a['block_a']});"
+            f"analytic;blocks=({a['block_e']},{a['block_s']},{a['block_d']});"
             f"dominant={dom};interpret_match={ok}",
         ))
     return rows

@@ -2,7 +2,7 @@
 
 Walks each kernel module's ``pl.pallas_call`` **BlockSpecs symbolically**
 (no JAX import, no execution): the block shapes are AST expressions over
-the block-size parameters (``block_e``, ``block_a``, ...), so for any
+the block-size parameters (``block_e``, ``block_s``, ...), so for any
 concrete assignment of those parameters the checker can
 
 * bound the **VMEM working set** per grid step — Σ over operand/output
@@ -89,12 +89,13 @@ KERNEL_TABLE: Dict[str, KernelSpec] = {
     "dfg_count": KernelSpec(
         rel="kernels/dfg_count/kernel.py",
         calls=(
-            # two (BA, BE) f32 one-hot tiles feed the MXU contraction
+            # the (BS, BE) and (BD, BE) int8 one-hots feed the MXU
+            # contraction into the resident (BS, BD) int32 output tile
             CallSpec("plain", ("int32", "int32"),
-                     "2 * 4 * block_e * block_a"),
+                     "block_e * (block_s + block_d)"),
             CallSpec("diced",
                      ("int32", "int32", "float32", "float32", "float32"),
-                     "2 * 4 * block_e * block_a"),
+                     "block_e * (block_s + block_d)"),
         ),
     ),
     "segment_count": KernelSpec(
@@ -186,12 +187,15 @@ def analyze_kernel(path: str) -> Tuple[CallSite, ...]:
     tree = ast.parse(Path(path).read_text(), filename=str(path))
     sites: List[CallSite] = []
     for fn in [n for n in tree.body if isinstance(n, ast.FunctionDef)]:
-        symbols: Dict[str, ast.Call] = {}
+        symbols: Dict[str, ast.Call] = {}  # BlockSpecs
+        calls: Dict[str, ast.Call] = {}  # any call, for a named out_shape
         for node in ast.walk(fn):
-            if isinstance(node, ast.Assign) and _is_blockspec(node.value):
+            if isinstance(node, ast.Assign) and isinstance(node.value, ast.Call):
                 for t in node.targets:
                     if isinstance(t, ast.Name):
-                        symbols[t.id] = node.value
+                        calls[t.id] = node.value
+                        if _is_blockspec(node.value):
+                            symbols[t.id] = node.value
         for node in ast.walk(fn):
             if not (
                 isinstance(node, ast.Call)
@@ -214,6 +218,8 @@ def analyze_kernel(path: str) -> Tuple[CallSite, ...]:
             )
             out_dtype = "float32"
             shape = kwargs.get("out_shape")
+            if isinstance(shape, ast.Name):
+                shape = calls.get(shape.id)
             if (
                 isinstance(shape, ast.Call)
                 and len(shape.args) > 1
@@ -455,9 +461,11 @@ def _scenario_envs(kernel_name: str) -> List[Tuple[str, Dict[str, int]]]:
         from repro.kernels.dfg_count.ops import pick_blocks
 
         out = []
-        for a in (64, 512, 2048):
-            be, ba = pick_blocks(a)
-            out.append((f"A={a}", {"block_e": be, "block_a": ba}))
+        for a in (64, 600, 3000):
+            be, bs, bd = pick_blocks(a)
+            out.append((
+                f"A={a}", {"block_e": be, "block_s": bs, "block_d": bd}
+            ))
         return out
     if kernel_name == "segment_count":
         from repro.kernels.segment_count.ops import pick_blocks

@@ -349,12 +349,16 @@ def test_validate_blocks_passes_for_picked_blocks():
 
 def test_validate_blocks_rejects_vmem_overrun():
     with pytest.raises(KernelResourceError, match="VMEM"):
-        validate_blocks("dfg_count", block_e=1 << 20, block_a=512)
+        validate_blocks(
+            "dfg_count", block_e=1 << 20, block_s=512, block_d=512
+        )
 
 
 def test_validate_blocks_rejects_misaligned_lane():
     with pytest.raises(KernelResourceError, match="multiple of 128"):
-        validate_blocks("dfg_count", block_e=1536, block_a=384 + 12)
+        validate_blocks(
+            "dfg_count", block_e=1536, block_s=384, block_d=384 + 12
+        )
 
 
 def test_validate_blocks_requires_full_env():

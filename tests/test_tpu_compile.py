@@ -2,9 +2,10 @@
 
 Nothing runs: each kernel is lowered and compiled by the TPU compiler
 against a ``v5e:2x2`` topology description, at the paper deployment's
-widths (7.2M pairs, 600 activities) and at a small vocabulary (26).  This
-catches what interpret mode cannot: layouts Mosaic refuses, primitives it
-cannot lower, tiles that overrun VMEM.
+widths (7.2M pairs, 600 activities) and at a small vocabulary (26); the DFG
+kernels also at a vocabulary that splits their output into several tiles
+(3000).  This catches what interpret mode cannot: layouts Mosaic refuses,
+primitives it cannot lower, tiles that overrun VMEM.
 
 The topology is described only inside the module-scoped fixture, never at
 import time: only one process may load the TPU library at a time.
@@ -26,6 +27,8 @@ from repro.kernels.segment_count import ops as seg_ops
 
 PAIRS = PAPER_EVAL.num_events
 VOCABULARIES = (26, PAPER_EVAL.num_activities)
+#: the first vocabulary past one resident DFG tile is 1281
+MULTI_TILE = 3000
 #: the paper log's 1-day window: ~4.4k variants, traces up to 114 events
 VARIANTS, TRACE_LEN = 4370, 114
 
@@ -74,6 +77,24 @@ def _kernel(name):
                    name).__wrapped_kernel__
 
 
+def _compile_dfg(kernel, a, spec):
+    fn = _kernel(kernel)
+    ids = spec((PAIRS,), jnp.int32)
+    mask = spec((PAIRS,), jnp.bool_)
+    if kernel == "dfg_count":
+        return _compile(
+            lambda s, d, v: fn(s, d, v, num_activities=a, interpret=False),
+            ids, ids, mask,
+        )
+    ts = spec((PAIRS,), jnp.float32)
+    return _compile(
+        lambda s, d, v, t0, t1, w: fn(
+            s, d, v, t0, t1, w, num_activities=a, interpret=False
+        ),
+        ids, ids, mask, ts, ts, spec((2,), jnp.float32),
+    )
+
+
 @pytest.mark.parametrize("a", VOCABULARIES)
 @pytest.mark.parametrize(
     "kernel", ["dfg_count", "dfg_count_diced", "segment_count", "align_dp"]
@@ -84,21 +105,8 @@ def test_kernel_compiles_for_v5e(kernel, a, one_chip):
 
     ids = spec((PAIRS,), jnp.int32)
     mask = spec((PAIRS,), jnp.bool_)
-    if kernel == "dfg_count":
-        fn = _kernel(kernel)
-        _compile(
-            lambda s, d, v: fn(s, d, v, num_activities=a, interpret=False),
-            ids, ids, mask,
-        )
-    elif kernel == "dfg_count_diced":
-        fn = _kernel(kernel)
-        ts = spec((PAIRS,), jnp.float32)
-        _compile(
-            lambda s, d, v, t0, t1, w: fn(
-                s, d, v, t0, t1, w, num_activities=a, interpret=False
-            ),
-            ids, ids, mask, ts, ts, spec((2,), jnp.float32),
-        )
+    if kernel.startswith("dfg"):
+        _compile_dfg(kernel, a, spec)
     elif kernel == "segment_count":
         fn = _kernel(kernel)
         _compile(
@@ -117,3 +125,13 @@ def test_kernel_compiles_for_v5e(kernel, a, one_chip):
             spec((sp, sp), jnp.float32), spec((sp, 1), jnp.float32),
             spec((sp, 1), jnp.float32),
         )
+
+
+@pytest.mark.parametrize("kernel", ["dfg_count", "dfg_count_diced"])
+def test_dfg_multi_tile_compiles_for_v5e(kernel, one_chip):
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    block_e, block_s, block_d = dfg_ops.pick_blocks(MULTI_TILE)
+    assert -(-MULTI_TILE // block_s) * -(-MULTI_TILE // block_d) > 1
+    _compile_dfg(kernel, MULTI_TILE, spec)
