@@ -3,24 +3,26 @@
 log size (``configs/graphpm.PAPER_EVAL``: 7.2M events, 600 activities,
 mean trace length 12, 120 days), on data generated from ``--seed``.
 
-    python chip_smoke.py                # one chip, phases a-e
+    python chip_smoke.py                # one chip, phases a-f
     python chip_smoke.py --four-chips   # four chips: mesh paths only
 
 One chip, every phase checked against a numpy oracle on the same data:
 
   a  full-log DFG through ``Q.log(log).dfg()`` (backend ``pallas``);
   b  three accumulating dices of Experiment 2 (days 1, 30, 120) through the
-     fused ``dfg_count_diced`` kernel.  The kernel compares times in f32:
-     pairs whose in-window test differs between f32 and f64 are counted
-     and printed, and where there are any the oracle is evaluated on
-     f32-cast times (and labelled so);
+     fused ``dfg_count_diced`` kernel, which compares int32 time ranks:
+     exact against the float64 window test;
   c  the graph tier: the graph build runs ``dfg_count`` and
      ``segment_count`` on the device, then ``dfg(backend="graph")`` and
      ``process_map()`` answer from it;
   d  alignments of one 1-day window through the ``align_dp`` kernel;
   e  the serving front: ``POST /query`` to an in-process
      ``TransportServer`` on 127.0.0.1 for ``dfg``, ``process_map`` and
-     ``fitness``, each equal to the direct ``QueryService.query`` dict.
+     ``fitness``, each equal to the direct ``QueryService.query`` dict;
+  f  the dices of b on a copy of the log whose times are epoch seconds
+     (from 2016-01-01, about 1.45e9 s, far past 2**24 s): exact against the
+     float64 window test where a float32 one would miscount, and the pairs
+     it would miscount are printed.
 
 Four chips: ``QueryEngine(mesh=...)`` (backend ``distributed``) and the
 4-way ``partition_memmap_log`` merge on that mesh, each equal to the
@@ -54,6 +56,9 @@ DAY = 86400.0
 KERNELS = ("dfg_count", "dfg_count_diced", "segment_count", "align_dp")
 DICE_DAYS = (1, 30, 120)
 LOG_NAME = "paper"
+#: 2016-01-01T00:00:00Z in Unix seconds, the first day of the BPI Challenge
+#: 2017 log
+EPOCH_S = 1_451_606_400.0
 
 
 class PhaseFailed(AssertionError):
@@ -119,6 +124,17 @@ class Meter:
 # ---------------------------------------------------------------------------
 # data and oracles
 # ---------------------------------------------------------------------------
+
+
+def epoch_copy(log, workdir: str):
+    """The log with every time moved to epoch seconds (+ ``EPOCH_S``)."""
+    from repro.core.streaming import MemmapLog
+
+    w = MemmapLog.create(os.path.join(workdir, "epoch_log"), log.num_events,
+                         log.num_activities, log.num_traces)
+    for a, c, t in log.iter_chunks():
+        w.append(a, c, t + EPOCH_S)
+    return w.close()
 
 
 def make_log(workdir: str, seed: int):
@@ -211,11 +227,12 @@ def phase_full_dfg(rec, log, engine, orc):
     rec["oracle"] = exact(res.value, orc.psi, "full-log DFG")
 
 
-def phase_windowed_dfg(rec, log, engine, orc):
+def phase_windowed_dfg(rec, log, engine, orc, need_f32_misses=False):
     from repro.core.dfg import dfg_numpy
     from repro.query import Q
 
     rec["windows"] = []
+    f32_misses = 0
     for days in DICE_DAYS:
         t0, t1 = orc.t_min, orc.t_min + days * DAY
         res = Q.log(log).using(engine).window(t0, t1).dfg()
@@ -224,20 +241,16 @@ def phase_windowed_dfg(rec, log, engine, orc):
               f"day {days}: plan {phys.describe()}")
         in64, in32 = orc.window_masks(t0, t1)
         mismatches = int((orc.valid & (in64 != in32)).sum())
-        w = {"days": days, "backend": phys.backend,
-             "fused_dicing": phys.fused_dicing,
-             "f32_edge_mismatches": mismatches}
-        if mismatches == 0:
-            ref = dfg_numpy(orc.src, orc.dst, orc.valid & in64, orc.a)
-            w["oracle"] = exact(res.value, ref, f"day {days}") + " (f64 oracle)"
-        else:
-            ref = dfg_numpy(orc.src, orc.dst, orc.valid & in32, orc.a)
-            w["oracle"] = (
-                exact(res.value, ref, f"day {days}")
-                + f" vs the f32-cast oracle; the f64 oracle differs on "
-                f"{mismatches} pairs (f32 window test)"
-            )
-        rec["windows"].append(w)
+        f32_misses += mismatches
+        ref = dfg_numpy(orc.src, orc.dst, orc.valid & in64, orc.a)
+        rec["windows"].append({
+            "days": days, "t0": t0, "backend": phys.backend,
+            "fused_dicing": phys.fused_dicing,
+            "f32_edge_mismatches": mismatches,
+            "oracle": exact(res.value, ref, f"day {days}") + " (f64 oracle)",
+        })
+    if need_f32_misses:
+        check(f32_misses > 0, "no pair a float32 window test would miscount")
     rec["backend"] = "pallas"
 
 
@@ -363,6 +376,12 @@ def run_one_chip(args, meter, workdir, results) -> None:
         phase_alignments(rec, log, engine, orc, model, meter)
     with meter.phase("e_serving", results) as rec:
         phase_serving(rec, log, engine)
+    with meter.phase("f_epoch_windows", results) as rec:
+        epoch_log = epoch_copy(log, workdir)
+        epoch_orc = Oracle(epoch_log)
+        check(epoch_orc.t_min > 2 ** 24, f"times start at {epoch_orc.t_min}")
+        phase_windowed_dfg(rec, epoch_log, engine, epoch_orc,
+                           need_f32_misses=True)
 
 
 # ---------------------------------------------------------------------------

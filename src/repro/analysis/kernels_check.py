@@ -94,7 +94,7 @@ KERNEL_TABLE: Dict[str, KernelSpec] = {
             CallSpec("plain", ("int32", "int32"),
                      "block_e * (block_s + block_d)"),
             CallSpec("diced",
-                     ("int32", "int32", "float32", "float32", "float32"),
+                     ("int32", "int32", "int32", "int32", "int32"),
                      "block_e * (block_s + block_d)"),
         ),
     ),

@@ -27,6 +27,7 @@ import numpy as np
 __all__ = [
     "GraphRepo",
     "EventRepository",
+    "TimeRanks",
     "concat_repositories",
     "paper_example_repo",
 ]
@@ -166,6 +167,39 @@ class GraphRepo:
 # ---------------------------------------------------------------------------
 
 
+@dataclasses.dataclass(frozen=True, eq=False)
+class TimeRanks:
+    """The event times as exact int32 ranks.
+
+    ``distinct`` holds the sorted distinct times and ``rank[i]`` the index
+    of event ``i``'s time in it, so ``t0 <= t < t1`` holds exactly when
+    ``lo <= rank < hi`` for ``(lo, hi) = bounds(t0, t1)``.  This is exact
+    for any float64 time (epoch seconds, milliseconds, times past 2**24,
+    where a float32 test rounds events onto a window's edges) and needs
+    only that the log holds fewer than 2**31 distinct times.  ``times`` is
+    the column the ranks were built from.
+    """
+
+    times: np.ndarray
+    distinct: np.ndarray  # (U,) float64, sorted
+    rank: np.ndarray  # (E,) int32
+
+    @staticmethod
+    def build(times: np.ndarray) -> "TimeRanks":
+        distinct, rank = np.unique(np.asarray(times), return_inverse=True)
+        if distinct.shape[0] >= 1 << 31:
+            raise ValueError(
+                f"{distinct.shape[0]} distinct times do not fit int32 ranks"
+            )
+        return TimeRanks(times, distinct, rank.astype(np.int32).reshape(-1))
+
+    def bounds(self, t0: float, t1: float) -> np.ndarray:
+        """``[lo, hi]`` (int32): the ranks of the window ``[t0, t1)``."""
+        return np.searchsorted(
+            self.distinct, np.asarray([t0, t1], np.float64), side="left"
+        ).astype(np.int32)
+
+
 @dataclasses.dataclass
 class EventRepository:
     """Columnar event repository (canonical form).
@@ -175,6 +209,9 @@ class EventRepository:
     * events are **trace-contiguous**: all events of a trace are adjacent;
     * within a trace, events are sorted by ``event_time`` (stable);
     * ``event_trace`` is therefore non-decreasing.
+
+    The columns are not edited in place: derived state is memoized on the
+    repository (:meth:`time_ranks`), and a changed log is a new repository.
 
     The E×E "directly follows" relation is *implicit*: event ``i`` directly
     precedes ``i+1`` iff ``event_trace[i] == event_trace[i+1]``.  This is the
@@ -189,6 +226,9 @@ class EventRepository:
     trace_names: List[str]
     log_names: List[str]
     event_names: Optional[List[str]] = None
+    _time_ranks: Optional[TimeRanks] = dataclasses.field(
+        default=None, repr=False, compare=False
+    )
 
     # -- sizes --------------------------------------------------------------
     @property
@@ -388,6 +428,23 @@ class EventRepository:
                 ),
             )
         return out
+
+    # -- exact window test ------------------------------------------------------
+    def cached_time_ranks(self) -> Optional[TimeRanks]:
+        """The memoized :class:`TimeRanks` of ``event_time``; None until
+        :meth:`time_ranks` has built them, or once ``event_time`` is
+        another column."""
+        ranks = self._time_ranks
+        if ranks is None or ranks.times is not self.event_time:
+            return None
+        return ranks
+
+    def time_ranks(self) -> TimeRanks:
+        """:class:`TimeRanks` of ``event_time``, built once and memoized."""
+        ranks = self.cached_time_ranks()
+        if ranks is None:
+            ranks = self._time_ranks = TimeRanks.build(self.event_time)
+        return ranks
 
     # -- directly-follows pairs (the E×E relation, vectorized) ---------------
     def df_pairs(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:

@@ -3,7 +3,9 @@
 XES is the IEEE standard the paper's tooling (ProM, pm4py) consumes; the
 subset handled here is the one the paper's data model needs:
 ``concept:name`` on traces (case id) and events (activity), and
-``time:timestamp``.  CSV is the pragmatic interchange format
+``time:timestamp``: ISO-8601 dates as the standard and real exports (BPI
+Challenge logs) write them, read as float64 epoch seconds, or the float
+this writer emits.  CSV is the pragmatic interchange format
 (case, activity, timestamp columns).
 """
 
@@ -11,6 +13,7 @@ from __future__ import annotations
 
 import csv
 import xml.etree.ElementTree as ET
+from datetime import datetime, timezone
 from typing import Iterable, Optional, TextIO, Tuple
 
 from repro.core.repository import EventRepository
@@ -76,6 +79,20 @@ def write_xes(repo: EventRepository, f: TextIO) -> None:
     f.write(ET.tostring(root, encoding="unicode"))
 
 
+def _timestamp(value: str) -> float:
+    """A ``time:timestamp`` as float64 seconds: the float this module
+    writes, or an ISO-8601 date (``2016-01-01T09:51:15.304+01:00``) as
+    seconds since the Unix epoch, a date without an offset taken as UTC."""
+    try:
+        return float(value)
+    except ValueError:
+        pass
+    dt = datetime.fromisoformat(value)
+    if dt.tzinfo is None:
+        dt = dt.replace(tzinfo=timezone.utc)
+    return dt.timestamp()
+
+
 def read_xes(f: TextIO) -> EventRepository:
     root = ET.parse(f).getroot()
     cases, acts, times = [], [], []
@@ -92,7 +109,7 @@ def read_xes(f: TextIO) -> EventRepository:
                 if attr.get("key") == "concept:name":
                     act = attr.get("value")
                 if attr.get("key") == "time:timestamp":
-                    ts = float(attr.get("value"))
+                    ts = _timestamp(attr.get("value"))
             if act is None:
                 continue
             seq += 1.0

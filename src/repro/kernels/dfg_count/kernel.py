@@ -40,10 +40,11 @@ VMEM working set per step: the resident int32 ``(BS, BD)`` tile plus the
 two one-hots, ``BE · (BS + BD)`` bytes; A = 600 at BE = 2048 is
 1.5 MiB + 2.4 MiB, under the 8 MiB budget ``ops.pick_blocks`` tunes to.
 
-The fused **dicing** variant additionally streams the pair timestamps and
-applies ``t0 ≤ t < t1`` in-register — the paper's WHERE clause at zero extra
-HBM traffic (no filtered copy is ever materialized).  The window sits in
-SMEM as two f32 scalars.
+The fused **dicing** variant additionally streams the pairs' int32 time
+ranks (``EventRepository.time_ranks``) and applies ``lo ≤ rank < hi``
+in-register — the paper's WHERE clause ``t0 ≤ t < t1``, exact for any
+float64 time, at zero extra HBM traffic (no filtered copy is ever
+materialized).  The window sits in SMEM as the two int32 rank bounds.
 """
 
 from __future__ import annotations
@@ -90,8 +91,9 @@ def dfg_kernel(src_ref, dst_ref, out_ref):
     _accumulate(src_ref[...], dst_ref[...], out_ref)
 
 
-def dfg_dice_kernel(src_ref, dst_ref, ts_src_ref, ts_dst_ref, win_ref, out_ref):
-    """Fused dicing: a pair counts iff ``t0 <= t < t1`` for both endpoints.
+def dfg_dice_kernel(src_ref, dst_ref, rank_src_ref, rank_dst_ref, win_ref, out_ref):
+    """Fused dicing: a pair counts iff ``lo <= rank < hi`` for both
+    endpoints, the window ``[t0, t1)`` as time-rank bounds.
 
     Paper semantics — both endpoints of the pair must be inside the window."""
 
@@ -99,11 +101,11 @@ def dfg_dice_kernel(src_ref, dst_ref, ts_src_ref, ts_dst_ref, win_ref, out_ref):
     def _zero():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    t0 = win_ref[0]
-    t1 = win_ref[1]
-    ts_s = ts_src_ref[...]
-    ts_d = ts_dst_ref[...]
-    inside = (ts_s >= t0) & (ts_s < t1) & (ts_d >= t0) & (ts_d < t1)
+    lo = win_ref[0]
+    hi = win_ref[1]
+    r_s = rank_src_ref[...]
+    r_d = rank_dst_ref[...]
+    inside = (r_s >= lo) & (r_s < hi) & (r_d >= lo) & (r_d < hi)
     src = jnp.where(inside, src_ref[...], -1)
     _accumulate(src, dst_ref[...], out_ref)
 
@@ -117,8 +119,8 @@ def dfg_count_pallas(
     block_s: int,
     block_d: int,
     interpret: bool,
-    ts_src: jax.Array | None = None,
-    ts_dst: jax.Array | None = None,
+    rank_src: jax.Array | None = None,
+    rank_dst: jax.Array | None = None,
     window: jax.Array | None = None,
 ) -> jax.Array:
     """Raw pallas_call wrapper.  Event columns are ``(1, E)`` rows with
@@ -153,4 +155,4 @@ def dfg_count_pallas(
         out_specs=out_spec,
         out_shape=out_shape,
         interpret=interpret,
-    )(src, dst, ts_src, ts_dst, window)
+    )(src, dst, rank_src, rank_dst, window)

@@ -138,9 +138,9 @@ def dfg_count_diced(
     src: jax.Array,
     dst: jax.Array,
     valid: jax.Array,
-    ts_src: jax.Array,
-    ts_dst: jax.Array,
-    window: jax.Array,  # shape (2,): [t0, t1)
+    rank_src: jax.Array,
+    rank_dst: jax.Array,
+    window: jax.Array,  # shape (2,): [lo, hi) in time ranks
     *,
     num_activities: int,
     block_e: int | None = None,
@@ -148,15 +148,25 @@ def dfg_count_diced(
     block_d: int | None = None,
     interpret: bool | None = None,
 ) -> jax.Array:
-    """Fused WHERE-clause dicing + counting (paper §4, Experiment 2)."""
+    """Fused WHERE-clause dicing + counting (paper §4, Experiment 2).
+
+    ``rank_src`` / ``rank_dst`` are the pairs' integer time ranks and
+    ``window`` the rank bounds of ``[t0, t1)``
+    (``EventRepository.time_ranks``): the test is exact for any float64
+    time, where a float32 one rounds times past 2**24 onto window edges."""
+    for x in (rank_src, rank_dst, window):
+        if not jnp.issubdtype(x.dtype, jnp.integer):
+            raise TypeError(
+                f"dfg_count_diced takes integer time ranks, got {x.dtype}"
+            )
     if interpret is None:
         interpret = jax.default_backend() == "cpu"
     block_e, block_s, block_d = _blocks(
         num_activities, block_e, block_s, block_d
     )
-    src, dst, ts_src, ts_dst = _event_rows(
+    src, dst, rank_src, rank_dst = _event_rows(
         block_e, src, valid, dst.astype(jnp.int32),
-        ts_src.astype(jnp.float32), ts_dst.astype(jnp.float32),
+        rank_src.astype(jnp.int32), rank_dst.astype(jnp.int32),
     )
     out = dfg_count_pallas(
         src, dst,
@@ -165,9 +175,9 @@ def dfg_count_diced(
         block_s=block_s,
         block_d=block_d,
         interpret=interpret,
-        ts_src=ts_src,
-        ts_dst=ts_dst,
-        window=window.astype(jnp.float32).reshape(2),
+        rank_src=rank_src,
+        rank_dst=rank_dst,
+        window=window.astype(jnp.int32).reshape(2),
     )
     return out[:num_activities, :num_activities]
 

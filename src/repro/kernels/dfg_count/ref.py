@@ -29,16 +29,17 @@ def dfg_count_diced_ref(
     src: jax.Array,
     dst: jax.Array,
     valid: jax.Array,
-    ts_src: jax.Array,
-    ts_dst: jax.Array,
+    rank_src: jax.Array,
+    rank_dst: jax.Array,
     window: jax.Array,
     *,
     num_activities: int,
 ) -> jax.Array:
-    t0, t1 = window[0], window[1]
+    """The window ``[lo, hi)`` over int32 time ranks, as the kernel takes it."""
+    lo, hi = window[0], window[1]
     v = (
         valid
-        & (ts_src >= t0) & (ts_src < t1)
-        & (ts_dst >= t0) & (ts_dst < t1)
+        & (rank_src >= lo) & (rank_src < hi)
+        & (rank_dst >= lo) & (rank_dst < hi)
     )
     return dfg_count_ref(src, dst, v, num_activities=num_activities)

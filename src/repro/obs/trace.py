@@ -15,7 +15,8 @@ engine's own process mines as a DFG — see ``QueryEngine.own_telemetry``):
 ``merge`` → ``sink``
 
 A device count nests ``scan.prepare`` → ``scan.h2d`` → ``scan.device``
-inside ``scan``.
+inside ``scan``; the first windowed one on a repository builds its time
+ranks as ``time_rank`` inside ``scan.prepare``.
 
 While a JAX profiler session is active, ``begin``/``end`` also open and
 close a TraceMe named ``repro.<span>``, timed by the profiler's own clock,

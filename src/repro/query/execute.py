@@ -53,7 +53,11 @@ from repro.core.distributed import (
     merge_shard_counts,
     merge_shard_psis,
 )
-from repro.core.repository import EventRepository, concat_repositories
+from repro.core.repository import (
+    EventRepository,
+    TimeRanks,
+    concat_repositories,
+)
 from repro.core.streaming import MemmapLog, StreamingDFGMiner, memmap_log_name
 from repro.core.telemetry import EventCollector
 from repro.core.variants import trace_variants, variant_filtered_repository
@@ -451,7 +455,6 @@ class QueryEngine:
         mesh=None,
         tiny_pairs: Optional[int] = None,
         memory_budget_events: Optional[int] = None,
-        fused_dicing: bool = True,
         cache: Optional[QueryCache] = None,
         repo_memo_size: int = 4,
         calibration_path: Optional[str] = None,
@@ -570,6 +573,16 @@ class QueryEngine:
             "engine_h2d_bytes_total",
             "Bytes of columns put on the device by device counts",
         )
+        self._c_time_rank_builds = m.counter(
+            "engine_time_rank_builds_total",
+            "Time-rank columns built for the fused window test",
+        )
+        self._h_time_rank = m.histogram(
+            "engine_time_rank_seconds",
+            "Build time of one repository's time-rank column",
+        )
+        # one build per repository even when cold workers race to it
+        self._time_rank_lock = make_lock("QueryEngine.time_rank")
         install_gc_hook()
         # always-on per-query tracing + self-mining forensics: every
         # finished trace batches its spans into a bounded collector, so
@@ -606,9 +619,6 @@ class QueryEngine:
         # per-source topology-query (miss) counter feeding the crossover
         self._topo_seen: "OrderedDict[str, int]" = OrderedDict()  # guarded by _lock
         self._max_topo_seen = 512
-        # the fused Pallas WHERE clause compares f32 timestamps; leave it on
-        # unless your timestamps do not round-trip through f32
-        self.fused_dicing = fused_dicing
         self.cache = cache if cache is not None else QueryCache()
         # physical plans depend only on (canonical plan, source shape), not
         # on data bytes — keying on SourceInfo instead of the fingerprint
@@ -963,7 +973,6 @@ class QueryEngine:
             mesh=self.mesh,
             tiny_pairs=self.tiny_pairs,
             memory_budget_events=self.memory_budget_events,
-            fused_dicing=self.fused_dicing,
             graph_available=graph_available,
             replay_crossover=self.replay_crossover,
             sharded_crossover=self.sharded_crossover,
@@ -1021,7 +1030,6 @@ class QueryEngine:
             mesh=self.mesh,
             tiny_pairs=self.tiny_pairs,
             memory_budget_events=self.memory_budget_events,
-            fused_dicing=self.fused_dicing,
             graph_available=graph_available,
             replay_crossover=self.replay_crossover,
             sharded_crossover=self.sharded_crossover,
@@ -1922,20 +1930,18 @@ class QueryEngine:
                 self.mesh, np.asarray(src, np.int32), np.asarray(dst, np.int32),
                 np.asarray(valid, bool), a_count,
             )
-        fused = (
-            backend == "pallas" and physical.fused_dicing
-            and st.window is not None
-        )
+        fused = physical.fused_dicing and st.window is not None
         cols = [
             np.asarray(src, np.int32), np.asarray(dst, np.int32),
             np.asarray(valid, bool),
         ]
         if fused:
-            # one cast of the times; the pair columns are views of it
-            ts = np.asarray(repo.event_time, np.float32)
+            # the window as exact time-rank bounds; the pair columns are
+            # views of the repository's memoized rank column
+            ranks = self._time_ranks(repo)
             cols += [
-                ts[:-1], ts[1:],
-                np.asarray([st.window.t0, st.window.t1], np.float32),
+                ranks.rank[:-1], ranks.rank[1:],
+                ranks.bounds(st.window.t0, st.window.t1),
             ]
         phases.next("scan.h2d")
         on_device = jax.block_until_ready(jax.device_put(cols))
@@ -1950,6 +1956,27 @@ class QueryEngine:
             psi = dfg(*on_device, a_count, backend=backend)
         phases.next(None)
         return psi
+
+    def _time_ranks(self, repo: EventRepository) -> TimeRanks:
+        """The repository's memoized :class:`TimeRanks`; the first call per
+        repository builds them, as the ``time_rank`` span of the running
+        query.  A repository is one state of a log, so an append, which
+        makes a new one, never meets a stale rank column."""
+        ranks = repo.cached_time_ranks()
+        if ranks is not None:
+            return ranks
+        with self._time_rank_lock:
+            ranks = repo.cached_time_ranks()
+            if ranks is None:
+                tr = self._current_trace()
+                slot = tr.begin("time_rank") if tr is not None else None
+                t0 = time.perf_counter()
+                ranks = repo.time_ranks()
+                self._h_time_rank.observe(time.perf_counter() - t0)
+                self._c_time_rank_builds.inc()
+                if slot is not None:
+                    tr.end(slot)
+        return ranks
 
     def _histogram_on_repo(self, st: _Collected):
         repo = st.repo

@@ -23,9 +23,8 @@ graph built / amortized   ``graph`` — un-windowed topology sinks (DFG,
 Pushdown decisions recorded on the :class:`PhysicalPlan`:
 
 * ``row_range`` — the memmap chunk-time-index dice (paper Experiment 2);
-* ``fused_dicing`` — window evaluated inside the Pallas kernel (f32
-  timestamps; requires f32-exact times for bit-identity, which the engine's
-  ``fused_dicing`` flag gates);
+* ``fused_dicing`` — window evaluated inside the Pallas kernel, on the
+  repository's int32 time ranks (exact for any float64 time);
 * ``view_pushdown`` — when the projection shrinks the activity set, relabel
   pair columns to group ids *before* counting so the matmul/count runs at
   G×G instead of A×A;
@@ -602,7 +601,6 @@ def _plan_union(
     mesh,
     tiny_pairs: int,
     memory_budget_events: int,
-    fused_dicing: bool,
     replay_crossover: int = REPLAY_STREAMING_CROSSOVER,
 ) -> PhysicalPlan:
     """Union costing: every branch is costed on its own shape (one union may
@@ -662,7 +660,6 @@ def _plan_union(
             bplan, binfo,
             mesh=mesh, tiny_pairs=tiny_pairs,
             memory_budget_events=memory_budget_events,
-            fused_dicing=fused_dicing,
             replay_crossover=replay_crossover,
         )
         notes.append(f"branch[{name}]={bphys.backend}")
@@ -685,7 +682,6 @@ def _plan_sharded(
     mesh,
     tiny_pairs: int,
     memory_budget_events: int,
-    fused_dicing: bool,
     graph_available: bool,
     sharded_crossover: int,
 ) -> PhysicalPlan:
@@ -769,7 +765,7 @@ def _plan_sharded(
         return PhysicalPlan(
             backend=backend,
             materialize=True,
-            fused_dicing=fused_dicing and backend == "pallas" and windowed,
+            fused_dicing=backend == "pallas" and windowed,
             view_pushdown=view_pushdown,
             activities_as_output_mask=acts is not None and not view_pushdown,
             notes=tuple(notes),
@@ -808,7 +804,6 @@ def plan_physical(
     mesh=None,
     tiny_pairs: int = TINY_PAIRS,
     memory_budget_events: int = MEMORY_BUDGET_EVENTS,
-    fused_dicing: bool = True,
     graph_available: bool = False,
     replay_crossover: int = REPLAY_STREAMING_CROSSOVER,
     sharded_crossover: int = SHARDED_SINGLE_CROSSOVER,
@@ -862,7 +857,6 @@ def plan_physical(
             plan, info,
             mesh=mesh, tiny_pairs=tiny_pairs,
             memory_budget_events=memory_budget_events,
-            fused_dicing=fused_dicing,
             replay_crossover=replay_crossover,
         )
     if info.shards is not None:
@@ -870,7 +864,6 @@ def plan_physical(
             plan, info,
             mesh=mesh, tiny_pairs=tiny_pairs,
             memory_budget_events=memory_budget_events,
-            fused_dicing=fused_dicing,
             graph_available=graph_available,
             sharded_crossover=sharded_crossover,
         )
@@ -1040,12 +1033,7 @@ def plan_physical(
             view_pushdown = True
             notes.append(f"count_space=G×G ({len(labels)}<{info.num_activities})")
 
-    fuse = (
-        fused_dicing
-        and backend == "pallas"
-        and window is not None
-        and not window.empty
-    )
+    fuse = backend == "pallas" and window is not None and not window.empty
     return PhysicalPlan(
         backend=backend,
         materialize=materialize,

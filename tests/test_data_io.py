@@ -62,6 +62,50 @@ def test_xes_roundtrip():
     )
 
 
+#: a BPI Challenge 2017 style export: ISO-8601 dates with offsets
+ISO_XES = """<log xes.version="1.0">
+<trace><string key="concept:name" value="Application_1"/>
+<event><string key="concept:name" value="A_Create Application"/>
+<date key="time:timestamp" value="2016-01-01T09:51:15.304+01:00"/></event>
+<event><string key="concept:name" value="A_Submitted"/>
+<date key="time:timestamp" value="2016-01-01T09:51:15.352+01:00"/></event>
+<event><string key="concept:name" value="W_Complete application"/>
+<date key="time:timestamp" value="2017-02-01T08:00:00Z"/></event>
+</trace>
+<trace><string key="concept:name" value="Application_2"/>
+<event><string key="concept:name" value="A_Create Application"/>
+<date key="time:timestamp" value="2016-06-30T23:59:59.999-02:00"/></event>
+<event><string key="concept:name" value="A_Submitted"/>
+<date key="time:timestamp" value="2016-07-01T02:00:00"/></event>
+</trace>
+</log>"""
+
+
+@pytest.mark.parametrize("case", ["iso_dates", "float_times"])
+def test_xes_timestamps_roundtrip(case):
+    if case == "iso_dates":
+        text = ISO_XES
+        want = [1451638275.304, 1451638275.352, 1485936000.0,
+                1467338399.999, 1467338400.0]
+    else:
+        repo = generate_repository(20, ProcessSpec(num_activities=5, seed=4))
+        # epoch times past 2**24 s with millisecond fractions
+        repo.event_time = 1.45e9 + np.round(repo.event_time * 1e3) / 1e3
+        buf = io.StringIO()
+        write_xes(repo, buf)
+        text = buf.getvalue()
+        want = repo.event_time.tolist()
+    first = read_xes(io.StringIO(text))
+    assert first.event_time.tolist() == want
+    # the float form this module writes reads back bit for bit
+    buf = io.StringIO()
+    write_xes(first, buf)
+    back = read_xes(io.StringIO(buf.getvalue()))
+    np.testing.assert_array_equal(back.event_time, first.event_time)
+    np.testing.assert_array_equal(back.event_activity, first.event_activity)
+    assert back.activity_names == first.activity_names
+
+
 def _dfg_by_name(repo):
     psi = dfg_from_repository(repo)
     out = {}

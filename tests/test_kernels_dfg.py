@@ -88,21 +88,24 @@ def test_kernel_dtypes(id_dtype, valid_dtype):
 
 
 # -- fused dicing vs oracle ----------------------------------------------------
-@pytest.mark.parametrize("window", [(0.0, 1.0), (0.2, 0.7), (0.9, 0.95), (2.0, 3.0)])
+# the kernel takes the pairs' int32 time ranks and the window as rank bounds
+@pytest.mark.parametrize(
+    "window", [(0, 1000), (200, 700), (900, 950), (2000, 3000)]
+)
 def test_diced_kernel_matches_ref(window):
     rng = np.random.default_rng(11)
     n, a = 2500, 40
     src, dst, valid = _random_pairs(rng, n, a)
-    ts_src = rng.random(n).astype(np.float32)
-    ts_dst = rng.random(n).astype(np.float32)
-    win = np.asarray(window, dtype=np.float32)
+    rank_src = rng.integers(0, 1000, size=n).astype(np.int32)
+    rank_dst = rng.integers(0, 1000, size=n).astype(np.int32)
+    win = np.asarray(window, dtype=np.int32)
     got = dfg_count_diced(
-        src, dst, valid, ts_src, ts_dst, win,
+        src, dst, valid, rank_src, rank_dst, win,
         num_activities=a, interpret=True,
     )
     want = dfg_count_diced_ref(
         jnp.asarray(src), jnp.asarray(dst), jnp.asarray(valid),
-        jnp.asarray(ts_src), jnp.asarray(ts_dst), jnp.asarray(win),
+        jnp.asarray(rank_src), jnp.asarray(rank_dst), jnp.asarray(win),
         num_activities=a,
     )
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -113,21 +116,21 @@ def test_diced_kernel_matches_ref(window):
     (200, 96, 128),    # 3 × 2 tiles
 ])
 def test_diced_window_edges_on_event_blocks(num_acts, block_s, block_d):
-    # sorted times, window [t[512], t[1536]): both edges fall on the first
-    # pair of an event block, so a block boundary off by one shows
+    # sorted ranks, window [rank[512], rank[1536]): both edges fall on the
+    # first pair of an event block, so a block boundary off by one shows
     rng = np.random.default_rng(5)
     n, be = 2500, 512
     src, dst, valid = _random_pairs(rng, n, num_acts)
-    ts_src = np.arange(n, dtype=np.float32)
-    ts_dst = ts_src + 0.5
-    win = np.asarray([ts_src[be], ts_src[3 * be]], dtype=np.float32)
+    rank_src = 2 * np.arange(n, dtype=np.int32)
+    rank_dst = rank_src + 1
+    win = np.asarray([rank_src[be], rank_src[3 * be]], dtype=np.int32)
     got = dfg_count_diced(
-        src, dst, valid, ts_src, ts_dst, win, num_activities=num_acts,
+        src, dst, valid, rank_src, rank_dst, win, num_activities=num_acts,
         block_e=be, block_s=block_s, block_d=block_d, interpret=True,
     )
     want = dfg_count_diced_ref(
         jnp.asarray(src), jnp.asarray(dst), jnp.asarray(valid),
-        jnp.asarray(ts_src), jnp.asarray(ts_dst), jnp.asarray(win),
+        jnp.asarray(rank_src), jnp.asarray(rank_dst), jnp.asarray(win),
         num_activities=num_acts,
     )
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -138,13 +141,24 @@ def test_diced_full_window_equals_undediced():
     rng = np.random.default_rng(3)
     n, a = 1500, 30
     src, dst, valid = _random_pairs(rng, n, a)
-    ts = rng.random(n).astype(np.float32)
-    win = np.asarray([0.0, 2.0], dtype=np.float32)
+    rank = rng.integers(0, n, size=n).astype(np.int32)
+    win = np.asarray([0, n], dtype=np.int32)
     a1 = dfg_count_diced(
-        src, dst, valid, ts, ts, win, num_activities=a, interpret=True
+        src, dst, valid, rank, rank, win, num_activities=a, interpret=True
     )
     a2 = dfg_count(src, dst, valid, num_activities=a, interpret=True)
     np.testing.assert_array_equal(np.asarray(a1), np.asarray(a2))
+
+
+def test_diced_refuses_float_times():
+    rng = np.random.default_rng(4)
+    src, dst, valid = _random_pairs(rng, 64, 8)
+    ts = rng.random(64).astype(np.float32)
+    with pytest.raises(TypeError, match="integer time ranks"):
+        dfg_count_diced(
+            src, dst, valid, ts, ts, np.asarray([0.0, 1.0], np.float32),
+            num_activities=8, interpret=True,
+        )
 
 
 # -- properties ---------------------------------------------------------------

@@ -541,7 +541,7 @@ def test_scan_phases_and_h2d_bytes_of_one_query(repo, monkeypatch):
     assert res.physical.fused_dicing
     snap = engine.metrics_snapshot()
     assert len(moved) == 1
-    # two int32 ids, one bool and two float32 times per pair, the window
+    # two int32 ids, one bool and two int32 time ranks per pair, the window
     assert snap["engine_h2d_bytes_total"] == moved[0]
     assert moved[0] == 17 * (repo.num_events - 1) + 8
     for phase in ("prepare", "h2d", "device"):

@@ -116,8 +116,8 @@ def test_combined_window_filter_view(repo, engine):
 
 
 def test_fused_pallas_dicing_equals_oracle(engine):
-    # integer timestamps (f32-exact) so the kernel's f32 WHERE clause is
-    # bit-identical to the f64 host mask
+    # whole-hour timestamps: many events share a time, so the window's
+    # edges fall on runs of equal time ranks
     repo = generate_repository(300, ProcessSpec(num_activities=7, seed=2))
     repo = dataclasses.replace(
         repo, event_time=np.floor(repo.event_time / 3600.0)

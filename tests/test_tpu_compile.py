@@ -86,12 +86,12 @@ def _compile_dfg(kernel, a, spec):
             lambda s, d, v: fn(s, d, v, num_activities=a, interpret=False),
             ids, ids, mask,
         )
-    ts = spec((PAIRS,), jnp.float32)
+    # the window test on int32 time ranks, the bounds in SMEM
     return _compile(
-        lambda s, d, v, t0, t1, w: fn(
-            s, d, v, t0, t1, w, num_activities=a, interpret=False
+        lambda s, d, v, r0, r1, w: fn(
+            s, d, v, r0, r1, w, num_activities=a, interpret=False
         ),
-        ids, ids, mask, ts, ts, spec((2,), jnp.float32),
+        ids, ids, mask, ids, ids, spec((2,), jnp.int32),
     )
 
 
