@@ -211,7 +211,8 @@ class EventRepository:
     * ``event_trace`` is therefore non-decreasing.
 
     The columns are not edited in place: derived state is memoized on the
-    repository (:meth:`time_ranks`), and a changed log is a new repository.
+    repository (:meth:`time_ranks`, :meth:`cached_device_pairs`), and a
+    changed log is a new repository.
 
     The E×E "directly follows" relation is *implicit*: event ``i`` directly
     precedes ``i+1`` iff ``event_trace[i] == event_trace[i+1]``.  This is the
@@ -227,6 +228,10 @@ class EventRepository:
     log_names: List[str]
     event_names: Optional[List[str]] = None
     _time_ranks: Optional[TimeRanks] = dataclasses.field(
+        default=None, repr=False, compare=False
+    )
+    # (the columns it was built from, the query engine's device pairs)
+    _device_pairs: Optional[tuple] = dataclasses.field(
         default=None, repr=False, compare=False
     )
 
@@ -445,6 +450,28 @@ class EventRepository:
         if ranks is None:
             ranks = self._time_ranks = TimeRanks.build(self.event_time)
         return ranks
+
+    def cached_device_pairs(self):
+        """The pair columns a query engine keeps on the device for this
+        repository (:mod:`repro.query.resident`); None until
+        :meth:`set_device_pairs`, or once ``event_activity``,
+        ``event_trace`` or ``event_time`` is another column."""
+        memo = self._device_pairs
+        if memo is None or any(
+            a is not b for a, b in zip(memo[0], self._pair_columns())
+        ):
+            return None
+        return memo[1]
+
+    def set_device_pairs(self, pairs) -> None:
+        """Keep ``pairs`` with the columns they were built from; None drops
+        them, and with them the repository's hold on their device memory."""
+        self._device_pairs = (
+            None if pairs is None else (self._pair_columns(), pairs)
+        )
+
+    def _pair_columns(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        return self.event_activity, self.event_trace, self.event_time
 
     # -- directly-follows pairs (the E×E relation, vectorized) ---------------
     def df_pairs(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:

@@ -16,7 +16,9 @@ engine's own process mines as a DFG — see ``QueryEngine.own_telemetry``):
 
 A device count nests ``scan.prepare`` → ``scan.h2d`` → ``scan.device``
 inside ``scan``; the first windowed one on a repository builds its time
-ranks as ``time_rank`` inside ``scan.prepare``.
+ranks as ``time_rank`` inside ``scan.prepare``, and the first one that
+reads the repository's own pairs puts them on the device as ``resident``
+there too.
 
 While a JAX profiler session is active, ``begin``/``end`` also open and
 close a TraceMe named ``repro.<span>``, timed by the profiler's own clock,

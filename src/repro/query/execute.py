@@ -108,6 +108,7 @@ from .cache import (
     realpath_of,
 )
 from .optimize import canonicalize, compose_views, distribute_over_union
+from .resident import ResidentPairs, build_resident_pairs
 from .planner import (
     PhysicalPlan,
     SourceInfo,
@@ -571,8 +572,23 @@ class QueryEngine:
         }
         self._c_h2d_bytes = m.counter(
             "engine_h2d_bytes_total",
-            "Bytes of columns put on the device by device counts",
+            "Bytes each device count puts on the device (resident builds "
+            "count in engine_resident_bytes_total)",
         )
+        self._c_scan_resident = m.counter(
+            "engine_scan_resident_total",
+            "Device counts read from a repository's resident pair columns",
+        )
+        self._c_resident_builds = m.counter(
+            "engine_resident_builds_total",
+            "Repositories whose pair columns were put on the device",
+        )
+        self._c_resident_bytes = m.counter(
+            "engine_resident_bytes_total",
+            "Bytes of resident pair columns put on the device",
+        )
+        # one resident set per repository even when cold workers race to it
+        self._resident_lock = make_lock("QueryEngine.resident")
         self._c_time_rank_builds = m.counter(
             "engine_time_rank_builds_total",
             "Time-rank columns built for the fused window test",
@@ -1555,10 +1571,7 @@ class QueryEngine:
             repo_u = concat_repositories(
                 named, activity_vocab=list(info.activity_names)
             )
-            with self._lock:
-                self._repo_memo[fp] = repo_u
-                while len(self._repo_memo) > self.repo_memo_size:
-                    self._repo_memo.popitem(last=False)
+            self._memoize_repo(fp, repo_u)
         # single-source execution on the concatenation, planned on its shape
         inner = LogicalPlan("repository", logical.ops, logical.sink)
         physical = self._plan_cached(inner, source_info(repo_u))
@@ -1856,11 +1869,18 @@ class QueryEngine:
                     return repo
         repo = repository_from_memmap(log, log_name)
         if fp is not None:
-            with self._lock:
-                self._repo_memo[fp] = repo
-                while len(self._repo_memo) > self.repo_memo_size:
-                    self._repo_memo.popitem(last=False)
+            self._memoize_repo(fp, repo)
         return repo
+
+    def _memoize_repo(self, fp: str, repo: EventRepository) -> None:
+        """Keep ``repo`` under ``fp``; a repository the LRU evicts drops its
+        resident device columns."""
+        with self._lock:
+            self._repo_memo[fp] = repo
+            while len(self._repo_memo) > self.repo_memo_size:
+                _, evicted = self._repo_memo.popitem(last=False)
+                if all(r is not evicted for r in self._repo_memo.values()):
+                    evicted.set_device_pairs(None)
 
     def _scan_phases(self, physical: PhysicalPlan) -> Optional[_ScanPhases]:
         """Open ``scan.prepare`` in the running query's trace where the
@@ -1869,36 +1889,52 @@ class QueryEngine:
             return None
         return _ScanPhases(self._current_trace(), self._h_scan_phase)
 
+    @staticmethod
+    def _own_pairs(st: _Collected, physical: PhysicalPlan) -> bool:
+        """The count reads the repository's own pairs as they are: a
+        ``pallas`` count, its window (if any) fused into the kernel, with no
+        view relabelling and no keep mask folded into ``valid``.  Such a
+        count reads the resident device columns
+        (:mod:`repro.query.resident`)."""
+        return (
+            physical.backend == "pallas"
+            and (st.window is None or physical.fused_dicing)
+            and not physical.view_pushdown
+            and (st.keep is None or physical.activities_as_output_mask)
+        )
+
     def _dfg_on_repo(
         self, st: _Collected, logical: LogicalPlan, physical: PhysicalPlan
     ):
         repo = st.repo
         names = list(repo.activity_names)
         phases = self._scan_phases(physical)
-        src, dst, valid = repo.df_pairs()
-        window_fused = physical.fused_dicing and st.window is not None
-
-        if st.window is not None and not window_fused:
-            valid = valid & pair_mask_for_window(repo, (st.window.t0, st.window.t1))
         keep_ids = None
         if st.keep is not None:
             keep_ids = np.asarray(
                 [names.index(a) for a in st.keep], dtype=np.int64
             )
-            if not physical.activities_as_output_mask:
+        a_count = repo.num_activities
+        if self._own_pairs(st, physical):
+            pairs = None
+        else:
+            src, dst, valid = repo.df_pairs()
+            if st.window is not None and not physical.fused_dicing:
+                valid = valid & pair_mask_for_window(
+                    repo, (st.window.t0, st.window.t1)
+                )
+            if keep_ids is not None and not physical.activities_as_output_mask:
                 m = np.isin(repo.event_activity, keep_ids)
                 if m.shape[0] >= 2:
                     valid = valid & m[:-1] & m[1:]
+            if physical.view_pushdown:
+                g, labels = st.view.to_view().group_matrix(names)
+                gmap = np.argmax(g, axis=1).astype(np.int32)
+                src, dst = gmap[src], gmap[dst]
+                a_count = len(labels)
+            pairs = (src, dst, valid)
 
-        if physical.view_pushdown:
-            g, labels = st.view.to_view().group_matrix(names)
-            gmap = np.argmax(g, axis=1).astype(np.int32)
-            src, dst = gmap[src], gmap[dst]
-            a_count = len(labels)
-        else:
-            a_count = repo.num_activities
-
-        psi = self._count(src, dst, valid, a_count, st, physical, repo, phases)
+        psi = self._count(pairs, a_count, st, physical, repo, phases)
 
         if physical.view_pushdown:
             vis = [i for i, l in enumerate(labels) if l != HIDDEN]
@@ -1911,40 +1947,50 @@ class QueryEngine:
         return psi, names
 
     def _count(
-        self, src, dst, valid, a_count, st: _Collected,
+        self, pairs, a_count, st: _Collected,
         physical: PhysicalPlan, repo: EventRepository,
         phases: Optional[_ScanPhases],
     ) -> np.ndarray:
-        """Ψ from pair columns.  On the device backends ``phases`` (opened
-        by the caller before it built the columns) closes ``scan.prepare``
-        once the columns have the kernel's dtypes, times their explicit
-        copy to the device as ``scan.h2d``, and the call until the counts
-        are back on the host as ``scan.device``."""
+        """Ψ from the pair columns ``pairs`` = (src, dst, valid), or, where
+        ``pairs`` is None, from the repository's own pairs resident on the
+        device.  On the device backends ``phases`` (opened by the caller
+        before it built the columns) closes ``scan.prepare`` once the
+        columns have the kernel's dtypes, times their explicit copy to the
+        device as ``scan.h2d`` (only the window's rank bounds on the
+        resident path), and the call until the counts are back on the host
+        as ``scan.device``."""
         backend = physical.backend
         if backend == "numpy":
-            return dfg_numpy(
-                np.asarray(src), np.asarray(dst), np.asarray(valid), a_count
-            )
+            return dfg_numpy(*(np.asarray(c) for c in pairs), a_count)
         if backend == "distributed":
+            src, dst, valid = pairs
             return distributed_dfg(
                 self.mesh, np.asarray(src, np.int32), np.asarray(dst, np.int32),
                 np.asarray(valid, bool), a_count,
             )
         fused = physical.fused_dicing and st.window is not None
-        cols = [
-            np.asarray(src, np.int32), np.asarray(dst, np.int32),
-            np.asarray(valid, bool),
-        ]
-        if fused:
-            # the window as exact time-rank bounds; the pair columns are
-            # views of the repository's memoized rank column
-            ranks = self._time_ranks(repo)
-            cols += [
-                ranks.rank[:-1], ranks.rank[1:],
-                ranks.bounds(st.window.t0, st.window.t1),
+        if pairs is None:
+            resident = self._resident_pairs(repo)
+            self._c_scan_resident.inc()
+            on_device = list(resident.cols if fused else resident.cols[:3])
+            ranks, cols = resident.ranks, []
+        else:
+            src, dst, valid = pairs
+            on_device = []
+            cols = [
+                np.asarray(src, np.int32), np.asarray(dst, np.int32),
+                np.asarray(valid, bool),
             ]
+            if fused:
+                # the pair columns are views of the repository's memoized
+                # rank column
+                ranks = self._time_ranks(repo)
+                cols += [ranks.rank[:-1], ranks.rank[1:]]
+        if fused:
+            # the window as exact time-rank bounds
+            cols.append(ranks.bounds(st.window.t0, st.window.t1))
         phases.next("scan.h2d")
-        on_device = jax.block_until_ready(jax.device_put(cols))
+        on_device += jax.block_until_ready(jax.device_put(cols))
         self._c_h2d_bytes.inc(sum(c.nbytes for c in cols))
         phases.next("scan.device")
         if fused:
@@ -1956,6 +2002,33 @@ class QueryEngine:
             psi = dfg(*on_device, a_count, backend=backend)
         phases.next(None)
         return psi
+
+    def _resident_pairs(self, repo: EventRepository) -> ResidentPairs:
+        """The repository's pairs on the device; the first call per
+        repository puts them there, as the ``resident`` span of the running
+        query.  They live as long as the repository holds them: until it is
+        freed or leaves the repository memo, and never past a change of its
+        columns."""
+        resident = repo.cached_device_pairs()
+        if resident is not None:
+            return resident
+        from repro.kernels.dfg_count.ops import pick_blocks
+
+        ranks = self._time_ranks(repo)
+        with self._resident_lock:
+            resident = repo.cached_device_pairs()
+            if resident is None:
+                tr = self._current_trace()
+                slot = tr.begin("resident") if tr is not None else None
+                resident = build_resident_pairs(
+                    repo, ranks, pick_blocks(repo.num_activities)[0]
+                )
+                repo.set_device_pairs(resident)
+                self._c_resident_builds.inc()
+                self._c_resident_bytes.inc(resident.nbytes)
+                if slot is not None:
+                    tr.end(slot)
+        return resident
 
     def _time_ranks(self, repo: EventRepository) -> TimeRanks:
         """The repository's memoized :class:`TimeRanks`; the first call per
@@ -2464,23 +2537,24 @@ class QueryEngine:
         planned backend (window as pair predicate or fused into the
         kernel), raw node counts alongside, then the shared derivation."""
         repo = st.repo
+        a = repo.num_activities
         phases = self._scan_phases(physical)
-        src, dst, valid = repo.df_pairs()
-        window_fused = physical.fused_dicing and st.window is not None
-        ev_mask = np.ones(repo.num_events, dtype=bool)
-        if st.window is not None:
+        window = st.window
+        acts = repo.event_activity
+        if window is not None:
             ts = repo.event_time
-            ev_mask = (ts >= st.window.t0) & (ts < st.window.t1)
-            if not window_fused:
+            acts = acts[(ts >= window.t0) & (ts < window.t1)]
+        if self._own_pairs(st, physical):
+            pairs = None
+        else:
+            src, dst, valid = repo.df_pairs()
+            if window is not None and not physical.fused_dicing:
                 valid = valid & pair_mask_for_window(
-                    repo, (st.window.t0, st.window.t1)
+                    repo, (window.t0, window.t1)
                 )
-        psi = self._count(
-            src, dst, valid, repo.num_activities, st, physical, repo, phases
-        )
-        counts = np.bincount(
-            repo.event_activity[ev_mask], minlength=repo.num_activities
-        ).astype(np.int64)
+            pairs = (src, dst, valid)
+        psi = self._count(pairs, a, st, physical, repo, phases)
+        counts = np.bincount(acts, minlength=a).astype(np.int64)
         return self._finish_topology(
             psi, counts, list(repo.activity_names), st, logical.sink
         )

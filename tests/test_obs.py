@@ -526,6 +526,8 @@ def test_no_trace_me_is_built_without_a_profiler_session(repo, monkeypatch,
 def test_scan_phases_and_h2d_bytes_of_one_query(repo, monkeypatch):
     import jax
 
+    from repro.kernels.dfg_count.ops import pick_blocks
+
     moved = []
     put = jax.device_put
 
@@ -540,10 +542,16 @@ def test_scan_phases_and_h2d_bytes_of_one_query(repo, monkeypatch):
     res = Q.log(repo).using(engine).window(t0, t1).dfg(backend="pallas")
     assert res.physical.fused_dicing
     snap = engine.metrics_snapshot()
-    assert len(moved) == 1
-    # two int32 ids, one bool and two int32 time ranks per pair, the window
-    assert snap["engine_h2d_bytes_total"] == moved[0]
-    assert moved[0] == 17 * (repo.num_events - 1) + 8
+    # the first count puts the repository's pairs on the device once,
+    # padded to the kernel's event block: two int32 ids, one bool and two
+    # int32 time ranks a pair; the query itself ships the window's bounds
+    block = pick_blocks(repo.num_activities)[0]
+    resident = 17 * (-(-(repo.num_events - 1) // block) * block)
+    assert moved == [resident, 8]
+    assert snap["engine_resident_builds_total"] == 1
+    assert snap["engine_resident_bytes_total"] == resident
+    assert snap["engine_h2d_bytes_total"] == 8
+    assert snap["engine_scan_resident_total"] == 1
     for phase in ("prepare", "h2d", "device"):
         h = snap[f"engine_scan_phase_seconds{{phase={phase}}}"]
         assert h["count"] == 1 and h["sum"] > 0.0
@@ -553,13 +561,29 @@ def test_scan_phases_and_h2d_bytes_of_one_query(repo, monkeypatch):
         s = spans[name]
         assert scan.start_s <= s.start_s
         assert s.start_s + s.duration_s <= scan.start_s + scan.duration_s
+    # the build is the query's ``resident`` span, inside ``scan.prepare``
+    prep, built = spans["scan.prepare"], spans["resident"]
+    assert prep.start_s <= built.start_s
+    assert built.start_s + built.duration_s <= prep.start_s + prep.duration_s
     # the same bits as the numpy count
     ref = Q.log(repo).using(QueryEngine()).window(t0, t1).dfg(backend="numpy")
+    np.testing.assert_array_equal(res.value, ref.value)
+    # a second window ships its 8 bytes and builds nothing
+    t2 = float(np.quantile(ts, 0.9))
+    res = Q.log(repo).using(engine).window(t0, t2).dfg(backend="pallas")
+    assert "resident" not in {s.name for s in res.trace.spans}
+    snap = engine.metrics_snapshot()
+    assert moved == [resident, 8, 8]
+    assert snap["engine_h2d_bytes_total"] == 16
+    assert snap["engine_resident_builds_total"] == 1
+    assert snap["engine_scan_resident_total"] == 2
+    ref = Q.log(repo).using(QueryEngine()).window(t0, t2).dfg(backend="numpy")
     np.testing.assert_array_equal(res.value, ref.value)
     # a count on the host opens no phase and moves nothing
     Q.log(repo).using(engine).dfg(backend="numpy")
     snap = engine.metrics_snapshot()
-    assert snap["engine_scan_phase_seconds{phase=h2d}"]["count"] == 1
+    assert snap["engine_scan_phase_seconds{phase=h2d}"]["count"] == 2
+    assert snap["engine_h2d_bytes_total"] == 16
 
 
 def test_coverage_counts_nested_spans_once():
